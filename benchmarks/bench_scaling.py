@@ -15,10 +15,13 @@ Two phases:
      for every device count (exit code 1 otherwise) — CI runs this on
      every push.
 
-  B. Throughput sweep (subprocess per device count, report-only on CPU
-     where host "devices" share cores): atoms/s of the balanced
-     StepPlan path vs the naive iterator across mesh sizes, via
-     ``XLA_FLAGS=--xla_force_host_platform_device_count``.
+  B. Throughput sweep (in this process, report-only on CPU where host
+     "devices" share cores): atoms/s of the balanced StepPlan path vs
+     the naive iterator over sub-meshes of ``jax.devices()`` — the first
+     ``n`` devices for each swept ``n`` that exists.  One process holds
+     every device, so on a TPU host the sweep times the chips, never a
+     child's CPU fallback; on CPU, ``XLA_FLAGS=
+     --xla_force_host_platform_device_count`` provides the devices.
 
     PYTHONPATH=src python benchmarks/bench_scaling.py --quick \
         --json bench_scaling.json
@@ -26,11 +29,11 @@ Two phases:
 from __future__ import annotations
 
 import argparse
+import itertools
 import json
 import os
-import subprocess
 import sys
-import textwrap
+import time
 
 import numpy as np
 
@@ -93,25 +96,21 @@ def run_straggler_analysis(
     return out
 
 
-_WORKER = textwrap.dedent("""
-    import os, sys, json, time, itertools
-    n = int(sys.argv[1]); batch = int(sys.argv[2])
-    steps = int(sys.argv[3]); mode = sys.argv[4]; quick = int(sys.argv[5])
-    os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
-    os.environ.setdefault("JAX_PLATFORM_NAME", "cpu")
-    import numpy as np, jax
+def _throughput(n, batch, steps, mode, quick):
+    """atoms/s of ``steps`` train steps on the first ``n`` devices."""
+    import jax
     from jax.sharding import Mesh
-    from repro.core.chgnet import CHGNetConfig
+
     from repro.batching import ladder_for
-    from repro.data import (BalancedBatchIterator, BatchIterator,
-                            SyntheticConfig, make_dataset)
+    from repro.core.chgnet import CHGNetConfig
+    from repro.data import BalancedBatchIterator, BatchIterator
     from repro.train import TrainConfig, Trainer
 
     ds = make_dataset(SyntheticConfig(
         num_crystals=64 if quick else 128, max_atoms=20 if quick else 32,
-        lognormal_sigma=1.1, seed=0))
+        lognormal_sigma=SKEW_SIGMA, seed=0))
     caps = ladder_for(ds, -(-batch // n))
-    mesh = Mesh(np.array(jax.devices()), ("data",)) if n > 1 else None
+    mesh = Mesh(np.array(jax.devices()[:n]), ("data",)) if n > 1 else None
     cfg = (CHGNetConfig(dim=16, num_blocks=1) if quick
            else CHGNetConfig(readout="direct"))
     tr = Trainer(cfg, TrainConfig(global_batch=batch), mesh=mesh)
@@ -127,34 +126,27 @@ _WORKER = textwrap.dedent("""
     t0 = time.perf_counter()
     tr.train(itertools.islice(cyc, steps))
     dt = (time.perf_counter() - t0) / steps
-    atoms_step = batch * float(np.mean(
-        [c.num_atoms for c in ds.crystals]))
-    print(json.dumps({"n": n, "mode": mode, "batch": batch,
-                      "step_s": dt, "atoms_per_s": atoms_step / dt}))
-""")
-
-
-def _run_worker(n, batch, steps, mode, quick):
-    env = dict(os.environ,
-               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
-                                       "src"))
-    out = subprocess.run(
-        [sys.executable, "-c", _WORKER, str(n), str(batch), str(steps),
-         mode, str(int(quick))],
-        capture_output=True, text=True, env=env, timeout=1800)
-    if out.returncode != 0:
-        raise RuntimeError(out.stderr[-1500:])
-    return json.loads(out.stdout.strip().splitlines()[-1])
+    atoms_step = batch * float(np.mean([c.num_atoms for c in ds.crystals]))
+    return {"n": n, "mode": mode, "batch": batch, "step_s": dt,
+            "atoms_per_s": atoms_step / dt,
+            "platform": jax.devices()[0].platform}
 
 
 def run_throughput_sweep(device_counts=(1, 2, 4), *, batch=16, steps=4,
                          quick=False) -> list[dict]:
     """Phase B: atoms/s vs mesh size, balanced vs naive (report-only on
-    CPU — forced host devices share the same cores)."""
+    CPU — forced host devices share the same cores).  Device counts
+    beyond ``jax.device_count()`` are skipped."""
+    import jax
+
     rows = []
     for n in device_counts:
+        if n > jax.device_count():
+            print(f"phase B: skip n={n}, only {jax.device_count()} "
+                  f"devices", file=sys.stderr)
+            continue
         for mode in ("naive", "balanced"):
-            rows.append(_run_worker(n, batch, steps, mode, quick))
+            rows.append(_throughput(n, batch, steps, mode, quick))
     return rows
 
 
@@ -180,7 +172,7 @@ def main():
     ap.add_argument("--batch", type=int, default=None)
     ap.add_argument("--steps", type=int, default=None)
     ap.add_argument("--skip-throughput", action="store_true",
-                    help="phase A only (no subprocess jax runs)")
+                    help="phase A only (no jax runs)")
     args = ap.parse_args()
 
     if args.devices:

@@ -20,6 +20,9 @@ def main() -> None:
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         bench_convergence, bench_fault, bench_iteration, bench_kernels,
         bench_md, bench_sampler, bench_scaling, bench_serve, roofline,

@@ -11,10 +11,12 @@ from repro.batching import capacity_for
 from repro.configs import chgnet_mptrj as C
 from repro.core.chgnet import chgnet_apply, chgnet_init, param_count
 from repro.data import BatchIterator, SyntheticConfig, make_dataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train import TrainConfig, Trainer
 
 
 def main():
+    enable_compile_cache()
     # 1. data: synthetic MPtrj-like crystals with analytic E/F/sigma/magmom
     ds = make_dataset(SyntheticConfig(num_crystals=64, max_atoms=24, seed=0))
     caps = capacity_for(ds, per_device_batch=8)

@@ -15,6 +15,7 @@ import numpy as np
 from repro.configs import chgnet_mptrj as C
 from repro.core.chgnet import chgnet_init
 from repro.core.neighbors import Crystal
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import BatchedMD, ServeEngine
 
 
@@ -36,6 +37,7 @@ def main():
     ap.add_argument("--dt", type=float, default=1e-3)
     ap.add_argument("--skin", type=float, default=0.5)
     args = ap.parse_args()
+    enable_compile_cache()
 
     # independent replicas of slightly different sizes — the bucket ladder
     # groups them so each group is one device program per step

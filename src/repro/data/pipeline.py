@@ -20,6 +20,7 @@ the consumer, not swallowed.
 """
 from __future__ import annotations
 
+import dataclasses
 import logging
 import math
 import queue
@@ -342,8 +343,26 @@ class BalancedBatchIterator:
             yield self.plan_step(idx)
 
 
+def place(item, device):
+    """``jax.device_put`` one stream item onto ``device`` (a device or a
+    sharding): a plain batch, a :class:`TaggedBatch`'s batch, or every
+    microbatch of a :class:`StepPlan` (its host-side plan metadata stays
+    on the host).  Stacked ``(n_dev, ...)`` batches placed with a
+    ``NamedSharding`` over the data axis land one shard per device, so
+    the step never stages the whole batch on device 0."""
+    if isinstance(item, TaggedBatch):
+        return item._replace(batch=place(item.batch, device))
+    if isinstance(item, StepPlan):
+        return dataclasses.replace(
+            item, micro=[jax.device_put(m, device) for m in item.micro])
+    return jax.device_put(item, device)
+
+
 class Prefetcher:
     """Background-thread prefetch of up to ``depth`` device-put batches.
+
+    ``device`` (a device or a sharding, see :func:`place`) is where each
+    item lands; ``None`` leaves items on the host.
 
     A worker-thread exception is captured and re-raised in the consumer at
     the point of failure — a bad batch must fail the epoch loudly, not
@@ -409,7 +428,7 @@ class Prefetcher:
                     continue
                 retries = 0
                 if self.device is not None:
-                    item = jax.device_put(item, self.device)
+                    item = place(item, self.device)
                 if not self._put(item):
                     return  # closed mid-put: consumer is gone
         except BaseException as e:  # re-raised in the consumer

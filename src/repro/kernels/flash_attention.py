@@ -80,7 +80,7 @@ def flash_attention_pallas(
     scale: float | None = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     bh, sq, d = q.shape
     sk = k.shape[1]

@@ -42,7 +42,7 @@ def fused_fourier_pallas(
     *,
     k_pad: int = 128,
     block_m: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     n = theta.shape[0]
     assert n % block_m == 0, (n, block_m)

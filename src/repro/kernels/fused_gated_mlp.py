@@ -57,7 +57,7 @@ def fused_gated_mlp_pallas(
     ln_bias: jnp.ndarray,   # (2*d_out,)
     *,
     block_m: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     m, d_in = x.shape
     two_d = w_packed.shape[1]

@@ -40,7 +40,7 @@ def fused_rbf_pallas(
     p: int = 8,
     *,
     block_m: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     n = dist.shape[0]
     k = freqs.shape[0]

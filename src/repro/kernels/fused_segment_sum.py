@@ -8,12 +8,14 @@ O(E*S) FLOPs.  This kernel exploits the sorted-segment batch layout
 (DESIGN.md §1) instead:
 
   - the grid walks *segment-row tiles* (``block_rows`` rows per program);
-  - CSR row pointers arrive via scalar prefetch, so each program knows its
-    edge range ``[offsets[r0], offsets[r0 + block_rows])`` before it runs;
+  - one CSR row pointer per tile arrives via scalar prefetch, so each
+    program knows its edge range ``[offsets[r0], offsets[r0 + block_rows])``
+    before it runs;
   - edges are consumed in ``chunk``-aligned slices; each slice builds a
-    *windowed* one-hot ``(chunk, block_rows)`` — bounded because sorted
-    edges of a row tile can only name segments inside that tile — and one
-    MXU contraction accumulates ``(block_rows, D)`` partial sums in VMEM.
+    *windowed* one-hot ``(block_rows, chunk)`` from the chunk's lane-dense
+    id row — bounded because sorted edges of a row tile can only name
+    segments inside that tile — and one MXU contraction accumulates
+    ``(block_rows, D)`` partial sums in VMEM.
 
 Every row is owned by exactly one program, so the reduction is
 deterministic (fixed chunk order, no atomics, no cross-tile carries) and
@@ -27,7 +29,7 @@ wrapper casts the sliced result back to the operand dtype.
 Residency tiers (DESIGN.md §9): with ``residency="vmem"`` values/segment
 ids are kept whole-array resident — fine for interpret mode (CI) and for
 CHGNet-scale bond tensors on TPU (~bond_cap x dim f32).
-``residency="hbm"`` leaves both in HBM (``pltpu.ANY``) and streams each
+``residency="hbm"`` leaves both in HBM (``pl.ANY``) and streams each
 chunk through ping/pong VMEM scratch with double-buffered async copies
 (``fused_message_passing._stream_loop``), so edge tensors that outgrow
 VMEM — 10k+-atom structures — reduce without whole-array residency.
@@ -46,24 +48,20 @@ def _kernel(offs_ref, seg_ref, val_ref, out_ref, *, block_rows: int,
             chunk: int):
     # windowed one-hot shared with the message-passing megakernels, which
     # generalize this reduction (DESIGN.md §3)
-    from .fused_message_passing import _window_onehot
+    from .fused_message_passing import _id_row, _mm, _window_onehot
 
     i = pl.program_id(0)
     r0 = i * block_rows
-    start = offs_ref[r0]
-    end = offs_ref[r0 + block_rows]
+    start = offs_ref[i]
+    end = offs_ref[i + 1]
     out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
 
     def body(k, carry):
         base = k * chunk  # chunk-aligned, so slices never straddle the cap
         v = val_ref[pl.ds(base, chunk), :]                     # (chunk, D)
-        s = seg_ref[pl.ds(base, chunk), :]                     # (chunk, 1)
-        onehot = _window_onehot(s, r0, start, end, base, chunk,
-                                block_rows).astype(v.dtype)
-        out_ref[...] += jax.lax.dot_general(
-            onehot, v, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).astype(out_ref.dtype)
+        onehot = _window_onehot(_id_row(seg_ref, k), r0, start, end, base,
+                                chunk, block_rows)     # (block_rows, chunk)
+        out_ref[...] += _mm(onehot, v).astype(out_ref.dtype)
         return carry
 
     jax.lax.fori_loop(start // chunk, pl.cdiv(end, chunk), body, 0)
@@ -74,59 +72,61 @@ def _kernel_hbm(offs_ref, seg_ref, val_ref, out_ref, seg_scr, val_scr,
     """HBM-residency tier (DESIGN.md §9): ids/values stream through
     ping/pong scratch, each next chunk's DMA overlapping the current
     chunk's windowed-one-hot contraction."""
-    from .fused_message_passing import _stream_loop, _window_onehot
+    from .fused_message_passing import _mm, _stream_loop, _window_onehot
 
     i = pl.program_id(0)
     r0 = i * block_rows
-    start = offs_ref[r0]
-    end = offs_ref[r0 + block_rows]
+    start = offs_ref[i]
+    end = offs_ref[i + 1]
     out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
     streams = ((seg_ref, seg_scr, seg_sem), (val_ref, val_scr, val_sem))
 
     def body(k, slot):
-        v = val_scr[slot]                                      # (chunk, D)
-        s = seg_scr[slot]                                      # (chunk, 1)
-        onehot = _window_onehot(s, r0, start, end, k * chunk, chunk,
-                                block_rows).astype(v.dtype)
-        out_ref[...] += jax.lax.dot_general(
-            onehot, v, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).astype(out_ref.dtype)
+        onehot = _window_onehot(seg_scr[slot], r0, start, end, k * chunk,
+                                chunk, block_rows)
+        out_ref[...] += _mm(onehot, val_scr[slot]).astype(out_ref.dtype)
 
-    _stream_loop(start // chunk, pl.cdiv(end, chunk), chunk, streams, body)
+    _stream_loop(start // chunk, pl.cdiv(end, chunk), streams, body)
 
 
 def fused_segment_sum_pallas(
     values: jnp.ndarray,   # (E, D) f32/bf16, E % chunk == 0, D % 128 == 0
-    seg_ids: jnp.ndarray,  # (E, 1) int32, sorted over the real prefix
+    seg_ids: jnp.ndarray,  # (E/chunk, chunk) int32 id rows, sorted over
+                           # the real prefix
     offsets: jnp.ndarray,  # (S + 1,) int32 CSR row pointers, S % block_rows == 0
     *,
+    interpret: bool,
     block_rows: int = 8,
     chunk: int = 256,
     residency: str = "vmem",
-    interpret: bool = True,
 ) -> jnp.ndarray:
-    from .fused_message_passing import _any_spec, _check_residency
+    from .fused_message_passing import (
+        _any_spec, _blocks, _check_residency, _compiler_params, _id_scratch,
+        _tile_offsets,
+    )
 
     e, d = values.shape
     s = offsets.shape[0] - 1
     hbm = _check_residency(residency)
-    assert e % chunk == 0, (e, chunk)
+    assert e % chunk == 0 and seg_ids.shape == (e // chunk, chunk), \
+        (e, seg_ids.shape, chunk)
     assert s % block_rows == 0, (s, block_rows)
     grid = (s // block_rows,)
     if hbm:
         in_specs = [_any_spec(), _any_spec()]
+        operands = (_blocks(seg_ids, 1), _blocks(values, chunk))
         scratch_shapes = [
-            pltpu.VMEM((2, chunk, 1), jnp.int32),
+            _id_scratch(chunk),
             pltpu.VMEM((2, chunk, d), values.dtype),
         ] + [pltpu.SemaphoreType.DMA((2,))] * 2
         kernel = functools.partial(_kernel_hbm, block_rows=block_rows,
                                    chunk=chunk)
     else:
         in_specs = [
-            pl.BlockSpec((e, 1), lambda i, offs: (0, 0)),
+            pl.BlockSpec(seg_ids.shape, lambda i, offs: (0, 0)),
             pl.BlockSpec((e, d), lambda i, offs: (0, 0)),
         ]
+        operands = (seg_ids, values)
         scratch_shapes = []
         kernel = functools.partial(_kernel, block_rows=block_rows,
                                    chunk=chunk)
@@ -141,5 +141,6 @@ def fused_segment_sum_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, d), jnp.float32),
+        compiler_params=_compiler_params(),
         interpret=interpret,
-    )(offsets, seg_ids, values)
+    )(_tile_offsets(offsets, block_rows), *operands)

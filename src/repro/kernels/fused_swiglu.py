@@ -55,7 +55,7 @@ def fused_swiglu_pallas(
     activation: str = "silu",
     block_m: int = 128,
     block_f: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     m, d = x.shape
     f = w_gate.shape[1]

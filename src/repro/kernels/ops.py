@@ -35,6 +35,7 @@ from .flash_attention import flash_attention_pallas
 from .fused_fourier import fused_fourier_pallas
 from .fused_gated_mlp import fused_gated_mlp_pallas
 from .fused_message_passing import (
+    _DIST_LANE,
     fused_atom_conv_pallas,
     fused_bond_conv_pallas,
     fused_force_readout_pallas,
@@ -98,13 +99,16 @@ def _itemsize(dtype) -> int:
 def estimate_table_bytes(num_atoms: int, num_bonds: int, num_angles: int,
                          dim: int, *, num_und: int | None = None,
                          itemsize: int = 4) -> int:
-    """Analytic operand-table bytes the §3 megakernels keep VMEM-resident
-    under ``table_residency="vmem"`` — the max over the atom_conv /
-    bond_conv / force-readout launches, mirroring the ops wrappers'
-    padding math (ids included).  Model-level twin of the per-launch
-    resolution inside each op: serve admission, the bench_iteration
-    residency bar, and the oversized-structure tests use it to decide
-    whether a batch is VMEM-feasible without tracing a kernel.
+    """Operand-table bytes the §3 megakernels keep VMEM-resident under
+    ``table_residency="vmem"`` — the max over the atom_conv / bond_conv /
+    force-readout launches, mirroring the ops wrappers' padding math and
+    the layout the chip gives each operand: feature tables at their
+    128-lane padded width, id streams as lane-dense ``(rows / chunk,
+    chunk)`` int32 rows (``_id_bytes``).  Model-level twin of the
+    per-launch resolution inside each op: serve admission, the
+    bench_iteration residency bar, and the oversized-structure tests use
+    it to decide whether a batch is VMEM-feasible without tracing a
+    kernel.
 
     ``num_und``: Eu rows of the §5 mirror tables (``bond_store=
     "undirected"``); None means the directed store.
@@ -112,21 +116,24 @@ def estimate_table_bytes(num_atoms: int, num_bonds: int, num_angles: int,
     dp = _round_up(max(dim, 1), _LANE)
     hp = dp
     mirror = num_und is not None
+    chunk = 256  # the conv/readout wrappers' edge chunk
     # atom_conv: ids (seg/nbr/pair) + v table + e payload + e^a
-    ep = _round_up(max(num_bonds, 1), 256)
+    ep = _round_up(max(num_bonds, 1), chunk)
     ap = _round_up(max(num_atoms, 1), math.lcm(8, 256))
     ea_rows = _round_up(max(num_und, 1), 256) if mirror else ep
-    atom = (3 * ep * 4 + ap * dp * itemsize + ep * dp * itemsize
-            + ea_rows * hp * itemsize)
+    atom = (3 * _id_bytes(ep, chunk) + ap * dp * itemsize
+            + ep * dp * itemsize + ea_rows * hp * itemsize)
     # bond_conv: ids (seg/ik/ctr/pij/pik) + v/e tables + a payload + e^b
-    epa = _round_up(max(num_angles, 1), 256)
+    epa = _round_up(max(num_angles, 1), chunk)
     bp = _round_up(max(num_bonds, 1), math.lcm(32, 512))
     apg = _round_up(max(num_atoms, 1), 512)
     eb_rows = _round_up(max(num_und, 1), 512) if mirror else bp
-    bond = (5 * epa * 4 + apg * dp * itemsize + bp * dp * itemsize
-            + epa * dp * itemsize + eb_rows * hp * itemsize)
-    # force readout: ids + e + x_hat (+ tiny virial extras)
-    force = ep * 4 * 3 + ep * dp * itemsize + ep * _LANE * itemsize
+    bond = (5 * _id_bytes(epa, chunk) + apg * dp * itemsize
+            + bp * dp * itemsize + epa * dp * itemsize
+            + eb_rows * hp * itemsize)
+    # force readout: ids (seg + virial cry) + e + x_hat
+    force = 2 * _id_bytes(ep, chunk) + ep * dp * itemsize \
+        + ep * _LANE * itemsize
     return max(atom, bond, force)
 
 
@@ -137,15 +144,17 @@ def resident_vmem_estimate(residency: str, num_atoms: int, num_bonds: int,
                            gather_tile: int = 512) -> int:
     """Deterministic resident-VMEM estimate per residency tier: the vmem
     tier holds the full operand tables (``estimate_table_bytes``); the hbm
-    tier holds only the ping/pong scratch — 2 slots x (chunk rows per edge
-    stream + gather_tile rows per table walk).  Backend-independent, so
-    the bench_iteration residency bar can be ENFORCED in interpret mode."""
+    tier holds only the ping/pong scratch — 2 slots x (one id block per id
+    stream, tiled to 8 sublanes, + chunk rows per payload stream +
+    gather_tile rows per table walk).  Backend-independent, so the
+    bench_iteration residency bar can be ENFORCED in interpret mode."""
     if residency == "vmem":
         return estimate_table_bytes(num_atoms, num_bonds, num_angles, dim,
                                     num_und=num_und, itemsize=itemsize)
     dp = _round_up(max(dim, 1), _LANE)
-    # worst launch is bond_conv: 6 edge streams + 3 gather-table walks
-    edge = 2 * chunk * (5 * 4 + dp * itemsize)
+    # worst launch is bond_conv: 5 id streams + the angle payload + 3
+    # gather-table walks
+    edge = 2 * (5 * 8 * chunk * 4 + chunk * dp * itemsize)
     gather = 2 * gather_tile * 3 * dp * itemsize
     return edge + gather
 
@@ -356,12 +365,12 @@ def _fused_segment_sum(values, segment_ids, offsets, num_segments,
     dp = _round_up(d, 128)
     sp = _round_up(num_segments, block_rows)
     values_p = jnp.pad(values, ((0, ep - e), (0, dp - d)))
-    seg_p = _pad_ids(segment_ids, ep)
+    seg_p = _pad_ids(segment_ids, ep, chunk)
     offs_p = _pad_offsets(offsets, sp)
     # auto resolves from the padded operand bytes (pure function of static
     # shapes, so forward and grad-of-forward pick the same tier)
     residency = _resolve_residency(
-        residency, ep * 4 + ep * dp * _itemsize(values.dtype))
+        residency, _id_bytes(ep, chunk) + ep * dp * _itemsize(values.dtype))
     out = fused_segment_sum_pallas(
         values_p, seg_p, offs_p,
         block_rows=block_rows, chunk=chunk, residency=residency,
@@ -450,8 +459,16 @@ def _chunk_of(x, i0, chunk: int):
     return jax.lax.dynamic_slice(x, (i0, 0), (chunk, x.shape[1]))
 
 
-def _pad_ids(ids, rows):
-    return _pad_rows_i32(ids, rows)[:, None]
+def _pad_ids(ids, rows, width):
+    """(n,) ids -> lane-dense ``(rows // width, width)`` int32 id rows,
+    one kernel chunk per row (the layout every megakernel streams)."""
+    return _pad_rows_i32(ids, rows).reshape(rows // width, width)
+
+
+def _id_bytes(rows: int, width: int) -> int:
+    """Bytes one padded id stream takes in VMEM: ``(rows // width,
+    width)`` int32 rows, the row count tiled to 8 sublanes."""
+    return _round_up(rows // width, 8) * width * 4
 
 
 def _pack_lanes_vec(vec, d, hp):
@@ -508,20 +525,21 @@ def _fused_atom_conv(v, e, e_a, w, b, ln_scale, ln_bias,
         # undirected store (DESIGN.md §5): e_a is an Eu-row table gathered
         # in-kernel through bond_pair — pad its rows to gather_tile windows
         ea_p = _pad2(e_a, _round_up(e_a.shape[0], gather_tile), hp)
-        pair_ids = _pad_ids(pair, ep)
+        pair_ids = _pad_ids(pair, ep, chunk)
     else:
         ea_p = _pad2(e_a, ep, hp)
-        pair_ids = _pad_ids(bond_center, ep)  # unused dummy, aliases seg
+        pair_ids = _pad_ids(bond_center, ep, chunk)  # unused dummy
     # auto: padded table bytes (ids + v + e + e^a) vs the VMEM budget —
     # pure function of static shapes, so fwd and grad-of-fwd agree
     residency = _resolve_residency(
         residency,
-        3 * ep * 4 + ap * dp * _itemsize(v.dtype)
+        3 * _id_bytes(ep, chunk) + ap * dp * _itemsize(v.dtype)
         + e_p.shape[0] * dp * _itemsize(e.dtype)
         + ea_p.shape[0] * hp * _itemsize(e_a.dtype))
     out = fused_atom_conv_pallas(
         _pad2(v, ap, dp), e_p, ea_p,
-        _pad_ids(bond_center, ep), _pad_ids(bond_nbr, ep), pair_ids,
+        _pad_ids(bond_center, ep, chunk), _pad_ids(bond_nbr, ep, chunk),
+        pair_ids,
         _pad_offsets(offsets, ap),
         _pack_lanes_w(w[:dim], dp, d, hp),
         _pack_lanes_w(w[dim:2 * dim], dp, d, hp),
@@ -709,21 +727,22 @@ def _fused_bond_conv(v, e, a, e_b, w, b, ln_scale, ln_bias,
         # envelope gathers run in-kernel through bond_pair[angle_*] (cheap
         # int gathers here — no float tensor is expanded for them)
         eb_p = _pad2(e_b, _round_up(e_b.shape[0], gather_tile), hp)
-        pij = _pad_ids(pair[angle_ij], ep)
-        pik = _pad_ids(pair[angle_ik], ep)
+        pij = _pad_ids(pair[angle_ij], ep, chunk)
+        pik = _pad_ids(pair[angle_ik], ep, chunk)
     else:
         eb_p = _pad2(e_b, bp, hp)
-        pij = _pad_ids(angle_ij, ep)   # unused dummies, alias seg/ik
-        pik = _pad_ids(angle_ik, ep)
+        pij = _pad_ids(angle_ij, ep, chunk)   # unused dummies
+        pik = _pad_ids(angle_ik, ep, chunk)
     residency = _resolve_residency(
         residency,
-        5 * ep * 4 + ap * dp * _itemsize(v.dtype)
+        5 * _id_bytes(ep, chunk) + ap * dp * _itemsize(v.dtype)
         + bp * dp * _itemsize(e.dtype) + ep * dp * _itemsize(a.dtype)
         + eb_p.shape[0] * hp * _itemsize(e_b.dtype))
     out = fused_bond_conv_pallas(
         _pad2(v, ap, dp), _pad2(e, bp, dp), _pad2(a, ep, dp), eb_p,
-        _pad_ids(angle_ij, ep), _pad_ids(angle_ik, ep),
-        _pad_ids(center_ids, ep), pij, pik, _pad_offsets(offsets, bp),
+        _pad_ids(angle_ij, ep, chunk), _pad_ids(angle_ik, ep, chunk),
+        _pad_ids(center_ids, ep, chunk), pij, pik,
+        _pad_offsets(offsets, bp),
         _pack_lanes_w(w[:dim], dp, d, hp),
         _pack_lanes_w(w[dim:2 * dim], dp, d, hp),
         _pack_lanes_w(w[2 * dim:3 * dim], dp, d, hp),
@@ -873,11 +892,13 @@ def _fused_sym_bond_conv(v, e, a_u, e_b, w, b, ln_scale, ln_bias,
         residency,
         ap * dp * _itemsize(v.dtype) + eup * dp * _itemsize(e.dtype)
         + eup * hp * _itemsize(e_b.dtype))
-    res_b = _resolve_residency(residency, 2 * icp * 4 + uap * hp * 4)
+    res_b = _resolve_residency(residency,
+                               2 * _id_bytes(icp, chunk) + uap * hp * 4)
     msg = fused_sym_msg_pallas(
         _pad2(v, ap, dp), _pad2(e, eup, dp), _pad2(a_u, uap, dp),
         _pad2(e_b, eup, hp),
-        _pad_ids(ctr, uap), _pad_ids(du1, uap), _pad_ids(du2, uap),
+        _pad_ids(ctr, uap, msg_block), _pad_ids(du1, uap, msg_block),
+        _pad_ids(du2, uap, msg_block),
         _pack_lanes_w(w[:dim], dp, d, hp),
         _pack_lanes_w(w[dim:2 * dim] + w[2 * dim:3 * dim], dp, d, hp),
         _pack_lanes_w(w[3 * dim:], dp, d, hp),
@@ -887,7 +908,7 @@ def _fused_sym_bond_conv(v, e, a_u, e_b, w, b, ln_scale, ln_bias,
         residency=res_a, interpret=_interpret(),
     )
     agg = fused_sym_accum_pallas(
-        msg, _pad_ids(dest, icp), _pad_ids(rep, icp),
+        msg, _pad_ids(dest, icp, chunk), _pad_ids(rep, icp, chunk),
         _pad_offsets(offsets, eup), eu_rows=eup, block_rows=block_rows,
         chunk=chunk, gather_tile=gather_tile, residency=res_b,
         interpret=_interpret(),
@@ -1011,11 +1032,11 @@ def _fused_force_readout(e, x_hat, w1, b1, w2, b2, bond_center, offsets,
     ap = _round_up(num_atoms, block_rows)
     ep = _round_up(e_rows, chunk)
     residency = _resolve_residency(
-        residency, ep * 4 + ep * dp * _itemsize(e.dtype)
+        residency, _id_bytes(ep, chunk) + ep * dp * _itemsize(e.dtype)
         + ep * xp * _itemsize(x_hat.dtype))
     out = fused_force_readout_pallas(
         _pad2(e, ep, dp), _pad2(x_hat, ep, xp),
-        _pad_ids(bond_center, ep), _pad_offsets(offsets, ap),
+        _pad_ids(bond_center, ep, chunk), _pad_offsets(offsets, ap),
         _pad2(w1, dp, dp), _pad2(b1[None, :], 1, dp),
         _pad2(w2.T, 1, dp), jnp.full((1, xp), b2[0], b2.dtype),
         block_rows=block_rows, chunk=chunk, residency=residency,
@@ -1111,16 +1132,19 @@ def _fused_force_virial_readout(e, x_hat, dist, w1, b1, w2, b2, bond_center,
     ap = _round_up(num_atoms, block_rows)
     bp = _round_up(num_crystals, block_rows)
     ep = _round_up(e_rows, chunk)
-    dist_p = jnp.pad(dist.astype(jnp.float32), (0, ep - e_rows))[:, None]
+    # the bond distance rides in x_hat's padding lane _DIST_LANE (one
+    # payload stream instead of a one-lane column)
+    xh_p = _pad2(x_hat, ep, xp).at[:e_rows, _DIST_LANE].set(
+        dist.astype(x_hat.dtype))
     residency = _resolve_residency(
-        residency, 2 * ep * 4 + ep * dp * _itemsize(e.dtype)
-        + ep * xp * _itemsize(x_hat.dtype) + ep * 4)
+        residency, 2 * _id_bytes(ep, chunk) + ep * dp * _itemsize(e.dtype)
+        + ep * xp * _itemsize(x_hat.dtype))
     out, sig = fused_force_readout_pallas(
-        _pad2(e, ep, dp), _pad2(x_hat, ep, xp),
-        _pad_ids(bond_center, ep), _pad_offsets(offsets, ap),
+        _pad2(e, ep, dp), xh_p,
+        _pad_ids(bond_center, ep, chunk), _pad_offsets(offsets, ap),
         _pad2(w1, dp, dp), _pad2(b1[None, :], 1, dp),
         _pad2(w2.T, 1, dp), jnp.full((1, xp), b2[0], b2.dtype),
-        cry=_pad_ids(bond_crystal, ep), dist=dist_p, num_crystals=bp,
+        cry=_pad_ids(bond_crystal, ep, chunk), num_crystals=bp,
         virial=True, block_rows=block_rows, chunk=chunk,
         residency=residency, interpret=_interpret(),
     )

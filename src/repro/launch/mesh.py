@@ -10,13 +10,8 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    # jax.sharding.AxisType only exists on newer jax; older versions get
-    # the same (Auto) behaviour by omitting axis_types entirely
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -7,9 +7,11 @@ Two modes:
   - ``--arch <lm-id>``: build + run the LM train step (smoke config on
     CPU; the full config is exercised by dryrun.py).
 
-On a real TPU pod this module is the per-host entrypoint
-(``jax.distributed.initialize()`` + the production mesh); on CPU it runs
-the same code paths on host devices.
+The CHGNet run uses every device of this one process: a data-parallel
+mesh over all local devices when there are several, a plain jitted step
+on one.  ``main(argv)`` returns the per-step metric history (each entry
+with its wall seconds, ``step_s``), so a caller can drive the launcher
+in-process.
 
     PYTHONPATH=src python -m repro.launch.train --arch chgnet --steps 50
 """
@@ -25,6 +27,8 @@ import jax
 def train_chgnet(args):
     from repro.batching import capacity_for, ladder_for
     from repro.configs import chgnet_mptrj as C
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
     from repro.data import (
         BatchIterator, Prefetcher, SyntheticConfig, make_dataset,
     )
@@ -80,6 +84,15 @@ def train_chgnet(args):
             ChaosSchedule.parse(args.chaos, seed=args.chaos_seed),
             ckpt_dir=args.ckpt)
     shutdown = GracefulShutdown().install()
+    history: list[dict] = []
+
+    def prefetch(stream, tr):
+        # stacked (n_dev, ...) batches go straight to their shards: one
+        # slice per device over the data axis, never the whole batch on
+        # device 0 first
+        device = None if tr.mesh is None \
+            else NamedSharding(tr.mesh, P(tr.mesh.axis_names[0]))
+        return Prefetcher(stream, device=device)
 
     def one_pass(tr):
         if args.balance == "cost" or args.accum > 1:
@@ -105,7 +118,7 @@ def train_chgnet(args):
                     # wrap INSIDE the Prefetcher so transient faults hit
                     # the worker's retry/quarantine path (DESIGN.md §8)
                     stream = monkey.wrap_batches(stream, start_step=tr.step)
-                return Prefetcher(stream)
+                return prefetch(stream, tr)
 
             hist = elastic_train(tr, batches_fn, max_steps=args.steps,
                                  fault_injector=monkey)
@@ -118,7 +131,7 @@ def train_chgnet(args):
                 itertools.cycle(iter(it)), args.steps - tr.step)
             if monkey is not None:
                 stream = monkey.wrap_batches(stream, start_step=tr.step)
-            hist = tr.train(Prefetcher(stream), fault_injector=monkey)
+            hist = tr.train(prefetch(stream, tr), fault_injector=monkey)
         return hist
 
     def loop(start):
@@ -130,6 +143,10 @@ def train_chgnet(args):
         while True:
             before = tr.step
             hist = one_pass(tr)
+            # each history entry pairs with the step's wall seconds, from
+            # dispatch to its loss on the host
+            walls = tr.straggler.times[len(tr.straggler.times) - len(hist):]
+            history.extend(dict(h, step_s=t) for h, t in zip(hist, walls))
             # a divergence rollback consumes stream batches while moving
             # tr.step backwards, so an exhausted stream can leave the run
             # short of --steps: rebuild the stream and keep going as long
@@ -148,16 +165,16 @@ def train_chgnet(args):
         # resume from the newest VALID checkpoint: a crash mid-write (or a
         # chaos ckpt_* event) leaves a corrupt newest file that restore
         # skips, so the resume step must skip it too
-        return run_with_restarts(
+        run_with_restarts(
             loop, resume_step_fn=lambda: (latest_valid_step(args.ckpt) or 0)
             if args.ckpt else 0,
             max_restarts=3)
     except PreemptionError as exc:
         print(f"preempted at step {exc.step}; checkpoint + resume marker "
               f"written to {args.ckpt}")
-        return exc.step
     finally:
         shutdown.uninstall()
+    return history
 
 
 def train_lm(args):
@@ -205,7 +222,11 @@ def train_lm(args):
     return args.steps
 
 
-def main():
+def main(argv: list[str] | None = None):
+    """Parse ``argv`` (default: the command line) and run; the CHGNet run
+    returns its per-step metric history."""
+    from repro.launch.compile_cache import enable_compile_cache
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="chgnet")
     ap.add_argument("--steps", type=int, default=50)
@@ -282,11 +303,11 @@ def main():
     ap.add_argument("--chaos-seed", type=int, default=0)
     ap.add_argument("--buckets", type=int, default=2,
                     help="capacity buckets (1 = single worst-case pad)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.arch == "chgnet":
-        train_chgnet(args)
-    else:
-        train_lm(args)
+        return train_chgnet(args)
+    return train_lm(args)
 
 
 if __name__ == "__main__":

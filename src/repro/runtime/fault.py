@@ -2,10 +2,11 @@
 detection, straggler watch (DESIGN.md §8).
 
 ``run_with_restarts`` wraps a training loop: on a *retryable* exception
-(preemption of a worker, OOM, injected fault) it restores from the newest
+(preemption of a worker, injected fault) it restores from the newest
 valid checkpoint and replays from there, up to ``max_restarts``.
-Programming errors (TypeError, ValueError, missing attributes/keys …) and
-graceful preemption (:class:`PreemptionError`) FAIL FAST instead of
+Programming errors (TypeError, ValueError, missing attributes/keys …),
+compiler refusals and device out-of-memory, and graceful preemption
+(:class:`PreemptionError`) FAIL FAST instead of
 looping through doomed restarts.  The loop function owns stepping and
 periodic checkpointing; this wrapper owns recovery.  Combined with atomic
 verified checkpoints this gives at-least-once step semantics with bounded
@@ -279,12 +280,32 @@ def clear_resume_marker(directory: str) -> None:
 # Exceptions restarting can never fix: programming/configuration errors
 # (the same code re-raises them deterministically) and graceful
 # preemption (the scheduler owns the restart).  Everything else — infra
-# flakes, injected faults, OOMs surfacing as RuntimeError — is retryable.
+# flakes, injected faults — is retryable, except the device errors
+# ``is_permanent_device_error`` names.
 NON_RETRYABLE = (
     TypeError, ValueError, KeyError, IndexError, AttributeError,
     NameError, ImportError, NotImplementedError, AssertionError,
     PreemptionError,
 )
+
+# XLA status codes and compiler messages that describe the program, not
+# the run: the same program fails the same way on every attempt
+_PERMANENT_XLA_STATUS = ("RESOURCE_EXHAUSTED", "INVALID_ARGUMENT",
+                         "UNIMPLEMENTED")
+_PERMANENT_XLA_TEXT = ("Mosaic failed to compile", "compile permanent error")
+
+
+def is_permanent_device_error(exc: BaseException) -> bool:
+    """True for a compiler refusal (Mosaic or XLA) or a device
+    out-of-memory.  A restart would rebuild and recompile the same
+    program at the same shapes, so retrying only delays the error."""
+    import jax
+
+    if not isinstance(exc, jax.errors.JaxRuntimeError):
+        return False
+    msg = str(exc)
+    return msg.startswith(_PERMANENT_XLA_STATUS) \
+        or any(t in msg for t in _PERMANENT_XLA_TEXT)
 
 
 def run_with_restarts(
@@ -300,14 +321,16 @@ def run_with_restarts(
     loop_fn must be restartable from any checkpointed step (pure training
     state lives in checkpoints, not Python locals).  ``retryable`` is an
     optional predicate overriding the default policy (retry everything
-    except :data:`NON_RETRYABLE`); note ``DeviceLossError`` is a
+    except :data:`NON_RETRYABLE` and :func:`is_permanent_device_error`
+    failures); note ``DeviceLossError`` is a
     RuntimeError and therefore retryable here, but the elastic path
     (``runtime.elastic.elastic_train``) normally absorbs it first.
     """
     def _should_retry(exc: BaseException) -> bool:
         if retryable is not None:
             return retryable(exc)
-        return not isinstance(exc, NON_RETRYABLE)
+        return not (isinstance(exc, NON_RETRYABLE)
+                    or is_permanent_device_error(exc))
 
     restarts = 0
     while True:

@@ -30,7 +30,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.batching import CompileCache, global_compile_cache
 from repro.batching.balance import StepPlan
@@ -337,12 +336,12 @@ def make_dp_train_step(model_cfg: CHGNetConfig, train_cfg: TrainConfig,
         return params, opt_state, dict(metrics, **extra)
 
     batch_spec = P(axis)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(P(), P(), batch_spec, P()),
         out_specs=(P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     # donate params/opt_state (same contract as the single-device step)
     return jax.jit(sharded, donate_argnums=(0, 1) if donate else ())
@@ -375,9 +374,9 @@ def make_dp_eval_step(model_cfg: CHGNetConfig, train_cfg: TrainConfig,
                                     train_cfg.loss)
         return jax.lax.pmean(metrics, axis)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local_eval, mesh=mesh,
-        in_specs=(P(), P(axis)), out_specs=P(), check_rep=False,
+        in_specs=(P(), P(axis)), out_specs=P(), check_vma=False,
     ), donate_argnums=(1,) if donate else ())
 
 
@@ -403,9 +402,9 @@ def make_dp_serve_step(model_cfg: CHGNetConfig, mesh: Mesh,
         out = chgnet_apply(params, model_cfg, local_batch)
         return jax.tree.map(lambda x: x[None], out)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local_serve, mesh=mesh,
-        in_specs=(P(), P(axis)), out_specs=P(axis), check_rep=False,
+        in_specs=(P(), P(axis)), out_specs=P(axis), check_vma=False,
     ), donate_argnums=(1,) if donate else ())
 
 
@@ -493,11 +492,11 @@ def make_chgnet_accum_step_fns(model_cfg: CHGNetConfig,
             sums = jax.lax.psum(sums, axis)
             return grads, sums
 
-        grad_step = jax.jit(shard_map(
+        grad_step = jax.jit(jax.shard_map(
             local_step, mesh=mesh,
             in_specs=(P(), P(axis), P(), P()),
             out_specs=(P(), P()),
-            check_rep=False,
+            check_vma=False,
         ))
 
     # donate params/opt_state only: grads' buffers can't back any output

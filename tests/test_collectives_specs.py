@@ -66,7 +66,6 @@ def test_long_context_skip_reasons():
 
 
 def test_bucketed_psum_single_device_identity():
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed import bucketed_psum, compressed_psum
@@ -75,12 +74,14 @@ def test_bucketed_psum_single_device_identity():
                          axis_types=(jax.sharding.AxisType.Auto,))
     tree = {"a": jnp.arange(4.0), "b": jnp.ones((3, 3))}
 
-    out = shard_map(lambda t: bucketed_psum(t, "data"), mesh=mesh,
-                    in_specs=(P(),), out_specs=P(), check_rep=False)(tree)
+    out = jax.shard_map(lambda t: bucketed_psum(t, "data"), mesh=mesh,
+                        in_specs=(P(),), out_specs=P(),
+                        check_vma=False)(tree)
     np.testing.assert_allclose(np.asarray(out["a"]), np.asarray(tree["a"]))
 
-    out2 = shard_map(lambda t: compressed_psum(t, "data"), mesh=mesh,
-                     in_specs=(P(),), out_specs=P(), check_rep=False)(tree)
+    out2 = jax.shard_map(lambda t: compressed_psum(t, "data"), mesh=mesh,
+                         in_specs=(P(),), out_specs=P(),
+                         check_vma=False)(tree)
     # bf16 rounding only
     np.testing.assert_allclose(np.asarray(out2["a"]), np.asarray(tree["a"]),
                                atol=2e-2)

@@ -299,6 +299,38 @@ def test_run_with_restarts_fails_fast_on_programming_errors():
     assert len(calls) == 1  # no doomed retries
 
 
+@pytest.mark.parametrize("msg", [
+    "INTERNAL: Mosaic failed to compile TPU kernel: Slice shape along "
+    "dimension 1 must be aligned to tiling (128), but is 1",
+    "RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem",
+    "RESOURCE_EXHAUSTED: Error allocating device buffer",
+])
+def test_run_with_restarts_fails_fast_on_compile_and_oom(msg):
+    calls = []
+
+    def loop(start):
+        calls.append(start)
+        raise jax.errors.JaxRuntimeError(msg)
+
+    with pytest.raises(jax.errors.JaxRuntimeError):
+        run_with_restarts(loop, resume_step_fn=lambda: 0, max_restarts=5)
+    assert len(calls) == 1  # the same program would fail the same way
+
+
+def test_run_with_restarts_retries_transient_device_errors():
+    calls = []
+
+    def loop(start):
+        calls.append(start)
+        if len(calls) < 3:
+            raise jax.errors.JaxRuntimeError("UNAVAILABLE: socket closed")
+        return "done"
+
+    assert run_with_restarts(loop, resume_step_fn=lambda: 0,
+                             max_restarts=5) == "done"
+    assert len(calls) == 3
+
+
 def test_run_with_restarts_never_retries_preemption():
     calls = []
 

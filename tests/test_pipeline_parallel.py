@@ -18,7 +18,6 @@ _SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.distributed.pipeline import gpipe_apply, split_stages
 
     L, D, M, MB = 8, 16, 6, 4   # layers, width, microbatches, microbatch sz
@@ -53,14 +52,15 @@ _SCRIPT = textwrap.dedent("""
     def pipe(staged, x):
         return gpipe_apply(staged, x, stage_fn, axis="pipe")
 
-    piped = shard_map(pipe, mesh=mesh, in_specs=(P("pipe"), P()),
-                      out_specs=P(), check_rep=False)(staged, x)
+    piped = jax.shard_map(pipe, mesh=mesh, in_specs=(P("pipe"), P()),
+                          out_specs=P(), check_vma=False)(staged, x)
     fwd_err = float(jnp.abs(piped - ref).max())
 
     # gradients through the pipeline == sequential gradients
     def loss_pipe(staged):
-        return jnp.sum(shard_map(pipe, mesh=mesh, in_specs=(P("pipe"), P()),
-                                 out_specs=P(), check_rep=False)(staged, x) ** 2)
+        return jnp.sum(jax.shard_map(
+            pipe, mesh=mesh, in_specs=(P("pipe"), P()), out_specs=P(),
+            check_vma=False)(staged, x) ** 2)
 
     def loss_seq(ws):
         return jnp.sum(jax.vmap(lambda xm: seq_forward(ws, xm))(x) ** 2)
