@@ -22,13 +22,12 @@ import itertools
 from functools import partial
 
 import jax
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 
 def train_chgnet(args):
     from repro.batching import capacity_for, ladder_for
     from repro.configs import chgnet_mptrj as C
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
     from repro.data import (
         BatchIterator, Prefetcher, SyntheticConfig, make_dataset,
     )
