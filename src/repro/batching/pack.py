@@ -46,6 +46,7 @@ from repro.core.neighbors import (
     build_angle_mirror_maps,
     build_mirror_maps,
 )
+from repro.runtime import spans
 
 from .capacity import BatchCapacities
 
@@ -275,43 +276,44 @@ def batch_crystals(
                                 und_angle_mask, sym_dest, sym_rep,
                                 sym_offsets)
 
-    return CrystalGraphBatch(
-        atom_z=jnp.asarray(atom_z),
-        atom_mask=jnp.asarray(atom_mask),
-        atom_crystal=jnp.asarray(atom_crystal),
-        frac_coords=jnp.asarray(frac),
-        lattice=jnp.asarray(lattice),
-        crystal_mask=jnp.asarray(crystal_mask),
-        bond_center=jnp.asarray(bond_center),
-        bond_nbr=jnp.asarray(bond_nbr),
-        bond_image=jnp.asarray(bond_image),
-        bond_crystal=jnp.asarray(bond_crystal),
-        bond_mask=jnp.asarray(bond_mask),
-        angle_ij=jnp.asarray(angle_ij),
-        angle_ik=jnp.asarray(angle_ik),
-        angle_mask=jnp.asarray(angle_mask),
-        bond_offsets=jnp.asarray(bond_offsets),
-        angle_offsets=jnp.asarray(angle_offsets),
-        bond_pair=jnp.asarray(bond_pair),
-        bond_sign=jnp.asarray(bond_sign),
-        und_center=jnp.asarray(und_center),
-        und_nbr=jnp.asarray(und_nbr),
-        und_image=jnp.asarray(und_image),
-        und_crystal=jnp.asarray(und_crystal),
-        und_mask=jnp.asarray(und_mask),
-        angle_pair=jnp.asarray(angle_pair),
-        und_angle_ij=jnp.asarray(und_angle_ij),
-        und_angle_ik=jnp.asarray(und_angle_ik),
-        und_angle_mask=jnp.asarray(und_angle_mask),
-        sym_dest=jnp.asarray(sym_dest),
-        sym_rep=jnp.asarray(sym_rep),
-        sym_offsets=jnp.asarray(sym_offsets),
-        energy=jnp.asarray(energy),
-        forces=jnp.asarray(forces),
-        stress=jnp.asarray(stress),
-        magmoms=jnp.asarray(magmoms),
-        n_atoms_per_crystal=jnp.asarray(n_atoms),
-    )
+    with spans.span("pack.h2d"):
+        return CrystalGraphBatch(
+            atom_z=jnp.asarray(atom_z),
+            atom_mask=jnp.asarray(atom_mask),
+            atom_crystal=jnp.asarray(atom_crystal),
+            frac_coords=jnp.asarray(frac),
+            lattice=jnp.asarray(lattice),
+            crystal_mask=jnp.asarray(crystal_mask),
+            bond_center=jnp.asarray(bond_center),
+            bond_nbr=jnp.asarray(bond_nbr),
+            bond_image=jnp.asarray(bond_image),
+            bond_crystal=jnp.asarray(bond_crystal),
+            bond_mask=jnp.asarray(bond_mask),
+            angle_ij=jnp.asarray(angle_ij),
+            angle_ik=jnp.asarray(angle_ik),
+            angle_mask=jnp.asarray(angle_mask),
+            bond_offsets=jnp.asarray(bond_offsets),
+            angle_offsets=jnp.asarray(angle_offsets),
+            bond_pair=jnp.asarray(bond_pair),
+            bond_sign=jnp.asarray(bond_sign),
+            und_center=jnp.asarray(und_center),
+            und_nbr=jnp.asarray(und_nbr),
+            und_image=jnp.asarray(und_image),
+            und_crystal=jnp.asarray(und_crystal),
+            und_mask=jnp.asarray(und_mask),
+            angle_pair=jnp.asarray(angle_pair),
+            und_angle_ij=jnp.asarray(und_angle_ij),
+            und_angle_ik=jnp.asarray(und_angle_ik),
+            und_angle_mask=jnp.asarray(und_angle_mask),
+            sym_dest=jnp.asarray(sym_dest),
+            sym_rep=jnp.asarray(sym_rep),
+            sym_offsets=jnp.asarray(sym_offsets),
+            energy=jnp.asarray(energy),
+            forces=jnp.asarray(forces),
+            stress=jnp.asarray(stress),
+            magmoms=jnp.asarray(magmoms),
+            n_atoms_per_crystal=jnp.asarray(n_atoms),
+        )
 
 
 def _check(cond: bool, msg: str) -> None:

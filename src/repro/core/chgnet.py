@@ -199,94 +199,103 @@ def _trunk(params, cfg: CHGNetConfig, graph: CrystalGraphBatch,
     # per directed bond) — e^a/e^b stay at Eu for the whole trunk.
     # Angle-pair dedup rides along: theta / Fourier / angle-embed run at
     # the Au == Na/2 dedup rows and expand via angle_pair below.
-    if cfg.bond_store == "undirected":
-        vec_und, dist_und, vec, dist, _cos, theta = \
-            basis.compute_geometry_undirected(
-                graph, displacement=displacement, strain=strain,
-                angle_rows="undirected",
+    with jax.named_scope("basis"):
+        if cfg.bond_store == "undirected":
+            vec_und, dist_und, vec, dist, _cos, theta = \
+                basis.compute_geometry_undirected(
+                    graph, displacement=displacement, strain=strain,
+                    angle_rows="undirected",
+                )
+            rbf_dist = dist_und
+        elif cfg.bond_store == "directed":
+            vec, dist, _cos, theta = basis.compute_geometry(
+                graph, displacement=displacement, strain=strain
             )
-        rbf_dist = dist_und
-    elif cfg.bond_store == "directed":
-        vec, dist, _cos, theta = basis.compute_geometry(
-            graph, displacement=displacement, strain=strain
-        )
-        vec_und = dist_und = None
-        rbf_dist = dist
-    else:
-        raise ValueError(f"unknown bond store {cfg.bond_store!r}")
-    if cfg.mlp_impl == "pallas":
-        from repro.kernels import ops as kops
+            vec_und = dist_und = None
+            rbf_dist = dist
+        else:
+            raise ValueError(f"unknown bond store {cfg.bond_store!r}")
+        if cfg.mlp_impl == "pallas":
+            from repro.kernels import ops as kops
 
-        rbf = kops.fused_rbf(
-            rbf_dist, params["rbf_freqs"], cfg.r_cut_atom, cfg.envelope_p
-        )
-        four = kops.fused_fourier(theta, cfg.num_fourier)
-    else:
-        rbf = basis.smooth_rbf(
-            rbf_dist, params["rbf_freqs"], cfg.r_cut_atom, cfg.envelope_p,
-            envelope=env,
-        )
-        four = basis.fourier_basis(theta, cfg.num_fourier)
+            rbf = kops.fused_rbf(
+                rbf_dist, params["rbf_freqs"], cfg.r_cut_atom,
+                cfg.envelope_p
+            )
+            four = kops.fused_fourier(theta, cfg.num_fourier)
+        else:
+            rbf = basis.smooth_rbf(
+                rbf_dist, params["rbf_freqs"], cfg.r_cut_atom,
+                cfg.envelope_p, envelope=env,
+            )
+            four = basis.fourier_basis(theta, cfg.num_fourier)
 
     # PRECISION BOUNDARY (DESIGN.md §4): geometry + basis above run in
     # f32 (accum-pinned); everything from the embedding GEMMs through the
     # interaction blocks runs at the policy's compute dtype.  Parameters
     # follow via the cast-to-compute views in linear/gated_mlp_apply.
-    cd = policy.compute
-    rbf = policy.cast_compute(rbf)
-    four = policy.cast_compute(four)
+    with jax.named_scope("embed"):
+        cd = policy.compute
+        rbf = policy.cast_compute(rbf)
+        four = policy.cast_compute(four)
 
-    # Feature embedding (packed bond linear -> split into e0 / e_a / e_b).
-    # Undirected store: the (rbf -> 3*dim) GEMM runs at Eu; e^a/e^b keep
-    # that granularity (the blocks never update them), e^0 expands once.
-    packed = linear_apply(params["bond_embed"], rbf)  # (Nb or Nu, 3*dim)
-    e0, e_a, e_b = jnp.split(packed, 3, axis=-1)
-    v = params["atom_embed"].astype(cd)[graph.atom_z] \
-        * graph.atom_mask[..., None].astype(cd)
-    if cfg.bond_store == "undirected":
-        # angle-pair dedup: ``four`` is at the Au dedup rows — embed once
-        # per unordered (ij, ik) pair, expand through angle_pair, and
-        # re-mask (padded angles carry pair=0)
-        a_und = linear_apply(params["angle_embed"], four) \
-            * graph.und_angle_mask[..., None].astype(cd)
-        umask = graph.und_mask[..., None].astype(cd)
-        e_a = e_a * umask
-        e_b = e_b * umask
-        if cfg.bond_features == "undirected":
-            # symmetric trunk (DESIGN.md §10): e stays Eu-resident and a
-            # stays Au-resident for the whole trunk — the blocks consume
-            # them through the mirror maps / sym-incidence store
-            a = a_und
-            e = e0 * umask
+        # Feature embedding (packed bond linear -> e0 / e_a / e_b).
+        # Undirected store: the (rbf -> 3*dim) GEMM runs at Eu; e^a/e^b
+        # keep that granularity (the blocks never update them), e^0
+        # expands once.
+        packed = linear_apply(params["bond_embed"], rbf)  # (Nb|Nu, 3*dim)
+        e0, e_a, e_b = jnp.split(packed, 3, axis=-1)
+        v = params["atom_embed"].astype(cd)[graph.atom_z] \
+            * graph.atom_mask[..., None].astype(cd)
+        if cfg.bond_store == "undirected":
+            # angle-pair dedup: ``four`` is at the Au dedup rows — embed
+            # once per unordered (ij, ik) pair, expand through angle_pair,
+            # and re-mask (padded angles carry pair=0)
+            a_und = linear_apply(params["angle_embed"], four) \
+                * graph.und_angle_mask[..., None].astype(cd)
+            umask = graph.und_mask[..., None].astype(cd)
+            e_a = e_a * umask
+            e_b = e_b * umask
+            if cfg.bond_features == "undirected":
+                # symmetric trunk (DESIGN.md §10): e stays Eu-resident and
+                # a stays Au-resident for the whole trunk — the blocks
+                # consume them through the mirror maps / sym-incidence
+                # store
+                a = a_und
+                e = e0 * umask
+            else:
+                a = a_und[graph.angle_pair] \
+                    * graph.angle_mask[..., None].astype(cd)
+                e = e0[graph.bond_pair] \
+                    * graph.bond_mask[..., None].astype(cd)
         else:
-            a = a_und[graph.angle_pair] \
+            a = linear_apply(params["angle_embed"], four) \
                 * graph.angle_mask[..., None].astype(cd)
-            e = e0[graph.bond_pair] * graph.bond_mask[..., None].astype(cd)
-    else:
-        a = linear_apply(params["angle_embed"], four) \
-            * graph.angle_mask[..., None].astype(cd)
-        e = e0 * graph.bond_mask[..., None].astype(cd)
+            e = e0 * graph.bond_mask[..., None].astype(cd)
 
-    for blk in params["blocks"]:
-        v, e, a = interaction_block_apply(
-            blk, graph, v, e, a, e_a, e_b,
-            variant=cfg.block_variant,
-            mlp_impl=cfg.mlp_impl,
-            agg_impl=cfg.agg_impl,
-            conv_impl=cfg.conv_impl,
-            bond_store=cfg.bond_store,
-            bond_features=cfg.bond_features,
-            table_residency=cfg.table_residency,
-        )
+    for i, blk in enumerate(params["blocks"]):
+        with jax.named_scope(f"block{i}"):
+            v, e, a = interaction_block_apply(
+                blk, graph, v, e, a, e_a, e_b,
+                variant=cfg.block_variant,
+                mlp_impl=cfg.mlp_impl,
+                agg_impl=cfg.agg_impl,
+                conv_impl=cfg.conv_impl,
+                bond_store=cfg.bond_store,
+                bond_features=cfg.bond_features,
+                table_residency=cfg.table_residency,
+            )
     # last block updates atoms only (matches CHGNet's final atom conv)
     from .interaction import atom_conv
 
-    v = atom_conv(
-        params["final_block"], graph, v, e, e_a,
-        mlp_impl=cfg.mlp_impl, agg_impl=cfg.agg_impl, conv_impl=cfg.conv_impl,
-        bond_store=cfg.bond_store, bond_features=cfg.bond_features,
-        table_residency=cfg.table_residency,
-    )
+    with jax.named_scope("final_block"), jax.named_scope("atom_conv"):
+        v = atom_conv(
+            params["final_block"], graph, v, e, e_a,
+            mlp_impl=cfg.mlp_impl, agg_impl=cfg.agg_impl,
+            conv_impl=cfg.conv_impl, bond_store=cfg.bond_store,
+            bond_features=cfg.bond_features,
+            table_residency=cfg.table_residency,
+        )
     # vec_und/dist_und (None for the directed store) ride along for the
     # bond_virial stress tier's undirected half-geometry path (§5/§7)
     return v, e, a, vec, dist, vec_und, dist_und
@@ -319,29 +328,33 @@ def chgnet_apply(params, cfg: CHGNetConfig, graph: CrystalGraphBatch):
 
     if cfg.readout == "direct":
         v, e, a, vec, dist, vec_und, dist_und = _trunk(params, cfg, graph)
-        if cfg.bond_features == "undirected":
-            # heads boundary (DESIGN.md §10): the force/stress heads read
-            # per-directed-bond features; expand the Eu-resident e ONCE
-            e = e[graph.bond_pair] * graph.bond_mask[..., None].astype(e.dtype)
-        energy = heads.energy_head_apply(params["energy_head"], graph, v)
-        magmom = heads.magmom_head_apply(params["magmom_head"], graph, v)
-        if cfg.stress_mode == "bond_virial":
-            # single-pass force + stress (DESIGN.md §7): with conv_impl=
-            # "fused" both come out of ONE megakernel launch
-            forces, stress = heads.force_virial_head_apply(
-                params["force_head"], graph, e, vec, dist,
-                vec_und=vec_und, dist_und=dist_und,
-                agg_impl=cfg.agg_impl, conv_impl=cfg.conv_impl,
-                bond_store=cfg.bond_store,
-                table_residency=cfg.table_residency)
-        elif cfg.stress_mode == "mlp":
-            forces = heads.force_head_apply(
-                params["force_head"], graph, e, vec, dist,
-                agg_impl=cfg.agg_impl, conv_impl=cfg.conv_impl,
-                table_residency=cfg.table_residency)
-            stress = heads.stress_head_apply(params["stress_head"], graph, v)
-        else:
-            raise ValueError(f"unknown stress mode {cfg.stress_mode!r}")
+        with jax.named_scope("readout"):
+            if cfg.bond_features == "undirected":
+                # heads boundary (DESIGN.md §10): the force/stress heads
+                # read per-directed-bond features; expand the Eu-resident e
+                # ONCE
+                e = e[graph.bond_pair] \
+                    * graph.bond_mask[..., None].astype(e.dtype)
+            energy = heads.energy_head_apply(params["energy_head"], graph, v)
+            magmom = heads.magmom_head_apply(params["magmom_head"], graph, v)
+            if cfg.stress_mode == "bond_virial":
+                # single-pass force + stress (DESIGN.md §7): with conv_impl=
+                # "fused" both come out of ONE megakernel launch
+                forces, stress = heads.force_virial_head_apply(
+                    params["force_head"], graph, e, vec, dist,
+                    vec_und=vec_und, dist_und=dist_und,
+                    agg_impl=cfg.agg_impl, conv_impl=cfg.conv_impl,
+                    bond_store=cfg.bond_store,
+                    table_residency=cfg.table_residency)
+            elif cfg.stress_mode == "mlp":
+                forces = heads.force_head_apply(
+                    params["force_head"], graph, e, vec, dist,
+                    agg_impl=cfg.agg_impl, conv_impl=cfg.conv_impl,
+                    table_residency=cfg.table_residency)
+                stress = heads.stress_head_apply(params["stress_head"],
+                                                 graph, v)
+            else:
+                raise ValueError(f"unknown stress mode {cfg.stress_mode!r}")
         return _out({"energy": energy, "forces": forces, "stress": stress,
                      "magmom": magmom})
 
@@ -350,7 +363,9 @@ def chgnet_apply(params, cfg: CHGNetConfig, graph: CrystalGraphBatch):
             v = _trunk(
                 params, cfg, graph, displacement=disp, strain=strain
             )[0]
-            e_tot = heads.energy_head_apply(params["energy_head"], graph, v)
+            with jax.named_scope("readout"):
+                e_tot = heads.energy_head_apply(params["energy_head"], graph,
+                                                v)
             return jnp.sum(e_tot), v
 
         disp0 = jnp.zeros_like(graph.frac_coords)
@@ -358,12 +373,13 @@ def chgnet_apply(params, cfg: CHGNetConfig, graph: CrystalGraphBatch):
         (de_ddisp, de_dstrain), v = jax.grad(
             energy_of, argnums=(0, 1), has_aux=True
         )(disp0, strain0)
-        energy = heads.energy_head_apply(params["energy_head"], graph, v)
-        magmom = heads.magmom_head_apply(params["magmom_head"], graph, v)
-        forces = -de_ddisp * graph.atom_mask[..., None]
-        vol = _volume(graph.lattice)[:, None, None]
-        stress = de_dstrain / (vol + 1e-12) * EV_A3_TO_GPA
-        stress = stress * graph.crystal_mask[:, None, None]
+        with jax.named_scope("readout"):
+            energy = heads.energy_head_apply(params["energy_head"], graph, v)
+            magmom = heads.magmom_head_apply(params["magmom_head"], graph, v)
+            forces = -de_ddisp * graph.atom_mask[..., None]
+            vol = _volume(graph.lattice)[:, None, None]
+            stress = de_dstrain / (vol + 1e-12) * EV_A3_TO_GPA
+            stress = stress * graph.crystal_mask[:, None, None]
         return _out({"energy": energy, "forces": forces, "stress": stress,
                      "magmom": magmom})
 

@@ -487,31 +487,36 @@ def interaction_block_apply(
     and bond_conv/angle_update run their symmetrized forms.
     """
     sym = bond_features == "undirected"
-    v_new = atom_conv(p, graph, v, e, e_a, mlp_impl=mlp_impl,
-                      agg_impl=agg_impl, conv_impl=conv_impl,
-                      bond_store=bond_store, bond_features=bond_features,
-                      table_residency=table_residency)
+    with jax.named_scope("atom_conv"):
+        v_new = atom_conv(p, graph, v, e, e_a, mlp_impl=mlp_impl,
+                          agg_impl=agg_impl, conv_impl=conv_impl,
+                          bond_store=bond_store, bond_features=bond_features,
+                          table_residency=table_residency)
 
     def _bond(v_in):
         if sym:
-            return sym_bond_conv(
+            with jax.named_scope("sym_bond_conv"):
+                return sym_bond_conv(
+                    p, graph, v_in, e, a, e_b, mlp_impl=mlp_impl,
+                    agg_impl=agg_impl, conv_impl=conv_impl,
+                    table_residency=table_residency,
+                )
+        with jax.named_scope("bond_conv"):
+            return bond_conv(
                 p, graph, v_in, e, a, e_b, mlp_impl=mlp_impl,
                 agg_impl=agg_impl, conv_impl=conv_impl,
-                table_residency=table_residency,
+                bond_store=bond_store, table_residency=table_residency,
             )
-        return bond_conv(
-            p, graph, v_in, e, a, e_b, mlp_impl=mlp_impl, agg_impl=agg_impl,
-            conv_impl=conv_impl, bond_store=bond_store,
-            table_residency=table_residency,
-        )
 
     def _angle(v_in, e_in):
         if not update_angles:
             return a
         if sym:
-            return sym_angle_update(p, graph, v_in, e_in, a,
-                                    mlp_impl=mlp_impl)
-        return angle_update(p, graph, v_in, e_in, a, mlp_impl=mlp_impl)
+            with jax.named_scope("sym_angle_update"):
+                return sym_angle_update(p, graph, v_in, e_in, a,
+                                        mlp_impl=mlp_impl)
+        with jax.named_scope("angle_update"):
+            return angle_update(p, graph, v_in, e_in, a, mlp_impl=mlp_impl)
 
     if variant == "reference":
         e_new = _bond(v_new)

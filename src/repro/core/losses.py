@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -65,8 +66,10 @@ def _error_terms(pred: dict, graph: CrystalGraphBatch):
     return (e_err, cmask), (f_err, fmask), (s_err, smask), (m_err, amask)
 
 
+@jax.named_scope("loss")
 def chgnet_loss(pred: dict, graph: CrystalGraphBatch, w: LossWeights):
-    """Returns (scalar loss, metrics dict with per-target MAEs)."""
+    """Returns (scalar loss, metrics dict with per-target MAEs), under the
+    device scope ``loss``."""
     (e_err, cmask), (f_err, fmask), (s_err, smask), (m_err, amask) = \
         _error_terms(pred, graph)
 
@@ -110,6 +113,7 @@ def global_denominators(num_crystals: int, num_atoms: int) -> dict:
     }
 
 
+@jax.named_scope("loss")
 def chgnet_loss_sums(pred: dict, graph: CrystalGraphBatch, w: LossWeights,
                      denoms: dict):
     """Partial loss of one microbatch against GLOBAL denominators.

@@ -18,6 +18,8 @@ import dataclasses
 
 import numpy as np
 
+from repro.runtime import spans
+
 
 @dataclasses.dataclass
 class Crystal:
@@ -490,14 +492,15 @@ class VerletNeighborList:
         self._rebuild(crystal)
 
     def _rebuild(self, crystal: Crystal) -> None:
-        lat = np.asarray(crystal.lattice, dtype=np.float64)
-        frac = np.asarray(crystal.frac_coords, dtype=np.float64)
-        ci, nj, images, _ = _candidate_pairs(
-            lat, frac, self.r_cut_atom + self.skin
-        )
-        self._ci, self._nj, self._images = ci, nj, images
-        self._ref_lat = lat.copy()
-        self._ref_frac = frac.copy()
+        with spans.span("nlist.rebuild"):
+            lat = np.asarray(crystal.lattice, dtype=np.float64)
+            frac = np.asarray(crystal.frac_coords, dtype=np.float64)
+            ci, nj, images, _ = _candidate_pairs(
+                lat, frac, self.r_cut_atom + self.skin
+            )
+            self._ci, self._nj, self._images = ci, nj, images
+            self._ref_lat = lat.copy()
+            self._ref_frac = frac.copy()
         self.rebuilds += 1
 
     def max_displacement(self, crystal: Crystal) -> float:

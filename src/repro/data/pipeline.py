@@ -48,6 +48,7 @@ from repro.batching.balance import (
 from repro.batching.cost import DEFAULT_COST_MODEL, CostModel
 from repro.core.graph import CrystalGraphBatch
 from repro.core.losses import global_denominators
+from repro.runtime import spans
 from repro.runtime.fault import TransientSampleError
 from .sampler import CostBalanceSampler, DefaultSampler, LoadBalanceSampler
 from .synthetic import SyntheticDataset
@@ -411,7 +412,10 @@ class Prefetcher:
         try:
             while not self._closed.is_set():
                 try:
-                    item = next(self._source)
+                    with spans.span("data.produce"):
+                        item = next(self._source)
+                        if self.device is not None:
+                            item = place(item, self.device)
                 except StopIteration:
                     break
                 except TransientSampleError as exc:
@@ -427,8 +431,6 @@ class Prefetcher:
                     time.sleep(self.backoff * (2 ** (retries - 1)))
                     continue
                 retries = 0
-                if self.device is not None:
-                    item = place(item, self.device)
                 if not self._put(item):
                     return  # closed mid-put: consumer is gone
         except BaseException as e:  # re-raised in the consumer
@@ -451,7 +453,8 @@ class Prefetcher:
         try:
             while True:
                 try:
-                    item = self.q.get(timeout=0.1)
+                    with spans.span("data.wait"):
+                        item = self.q.get(timeout=0.1)
                 except queue.Empty:
                     if self._closed.is_set() or not self.thread.is_alive():
                         break  # worker gone without a sentinel

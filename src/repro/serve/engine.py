@@ -46,6 +46,7 @@ from repro.core.neighbors import (
     VerletNeighborList,
     build_graph,
 )
+from repro.runtime import spans
 
 
 def _next_pow2(k: int) -> int:
@@ -277,28 +278,45 @@ class BatchedMD:
                 yield bucket, ids[s:s + self.max_group]
 
     def step(self, n_steps: int = 1) -> dict:
-        """Advance every replica ``n_steps``; returns last-step outputs."""
+        """Advance every replica ``n_steps``; returns last-step outputs.
+
+        Each step is the host span ``repro.md.step`` (``step_num`` =
+        ``steps_done``) holding ``repro.md.nlist`` (every replica's
+        neighbor-list update), per replica group ``repro.md.pack`` and
+        ``repro.md.dispatch``, then ``repro.md.collect`` (waiting for the
+        device and the D2H copies) and ``repro.md.integrate``.
+        """
         last = {}
         for _ in range(n_steps):
+            with spans.step("md.step", self.steps_done):
+                last = self._step_once()
+            self.steps_done += 1
+        return last
+
+    def _step_once(self) -> dict:
+        with spans.span("md.nlist"):
             graphs = [r.nlist.update(r.crystal) for r in self.replicas]
-            energies = np.zeros(self.num_replicas)
-            forces_by_replica: list[np.ndarray | None] = [None] * self.num_replicas
-            # dispatch every group first (jax dispatch is async) so device
-            # compute of group k overlaps host packing of group k+1 ...
-            dispatched = []
-            for bucket, ids in self._grouped(graphs):
-                crystals = [self.replicas[i].crystal for i in ids]
-                slots = _next_pow2(len(ids))
-                caps = bucket.scaled(slots)
+        energies = np.zeros(self.num_replicas)
+        forces_by_replica: list[np.ndarray | None] = [None] * self.num_replicas
+        # dispatch every group first (jax dispatch is async) so device
+        # compute of group k overlaps host packing of group k+1 ...
+        dispatched = []
+        for bucket, ids in self._grouped(graphs):
+            crystals = [self.replicas[i].crystal for i in ids]
+            slots = _next_pow2(len(ids))
+            caps = bucket.scaled(slots)
+            with spans.span("md.pack"):
                 batch, _ = self.serve.engine.pack(
                     crystals, graphs=[graphs[i] for i in ids],
                     caps=caps, num_crystal_slots=slots,
                 )
+            with spans.span("md.dispatch"):
                 out = self.serve.step_fn(bucket, slots)(
                     self.serve.params, batch
                 )
-                dispatched.append((ids, crystals, out))
-            # ... then collect (np.asarray blocks per output)
+            dispatched.append((ids, crystals, out))
+        # ... then collect (np.asarray blocks per output)
+        with spans.span("md.collect"):
             for ids, crystals, out in dispatched:
                 f = np.asarray(out["forces"])
                 e = np.asarray(out["energy"])
@@ -307,14 +325,13 @@ class BatchedMD:
                     na = crystals[k].num_atoms
                     forces_by_replica[i] = f[offs[k]:offs[k] + na]
                     energies[i] = e[k]
-            # toy NVE update (unit masses) — exercises the serve path
+        # toy NVE update (unit masses) — exercises the serve path
+        with spans.span("md.integrate"):
             for r, f in zip(self.replicas, forces_by_replica):
                 r.velocities += f * self.dt
                 cart = r.crystal.cart_coords() + r.velocities * self.dt
                 r.crystal.frac_coords = (cart @ r.inv_lattice) % 1.0
-            self.steps_done += 1
-            last = {"energy": energies, "forces": forces_by_replica}
-        return last
+        return {"energy": energies, "forces": forces_by_replica}
 
     def stats(self) -> dict:
         s = self.serve.stats()

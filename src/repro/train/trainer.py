@@ -57,6 +57,7 @@ from repro.precision import (
     resolve_policy,
     scale_loss,
 )
+from repro.runtime import spans
 
 
 @dataclasses.dataclass(frozen=True)
@@ -121,10 +122,12 @@ def _scaled_chgnet_loss_fn(params, cfg, batch, weights, scaler):
     return loss, metrics
 
 
+@jax.named_scope("optimizer")
 def _apply_grads(grads, opt_state, params, lr, train_cfg: TrainConfig,
                  scale_kind: str):
     """Shared tail of every train step: (optionally) unscale -> clip ->
-    Adam -> skip-on-nonfinite -> scaler update (DESIGN.md §4).
+    Adam -> skip-on-nonfinite -> scaler update (DESIGN.md §4), all under
+    the device scope ``optimizer``.
 
     ``opt_state`` may carry a ``"loss_scale"`` subtree; its presence (a
     trace-time structure property) turns on the scaled path.  An
@@ -909,21 +912,31 @@ class Trainer:
             raise
 
     def _train_loop(self, batches, history, max_steps, fault_injector):
-        import numpy as np
-
-        from repro.data.pipeline import TaggedBatch
-
         for batch in batches:
             if max_steps is not None and self.step >= max_steps:
                 break
             if self.shutdown is not None and self.shutdown.requested:
                 self._preempt()
-            t0 = time.perf_counter()
-            if fault_injector is not None:
-                fault_injector.maybe_fail(self.step)
-            indices = None
-            if isinstance(batch, TaggedBatch):
-                indices, batch = batch.indices, batch.batch
+            with spans.step("train.step", self.step):
+                self._train_one(batch, history, fault_injector)
+        return history
+
+    def _train_one(self, batch, history, fault_injector) -> None:
+        """One optimizer step, inside the ``repro.train.step`` marker:
+        ``repro.train.dispatch`` (the step's call), ``repro.train.loss_read``
+        (the blocking read of its loss) and ``repro.train.ckpt`` (a
+        save).  A rolled-back or restored step appends no history."""
+        import numpy as np
+
+        from repro.data.pipeline import TaggedBatch
+
+        t0 = time.perf_counter()
+        if fault_injector is not None:
+            fault_injector.maybe_fail(self.step)
+        indices = None
+        if isinstance(batch, TaggedBatch):
+            indices, batch = batch.indices, batch.batch
+        with spans.span("train.dispatch"):
             if isinstance(batch, StepPlan):
                 self.params, self.opt_state, metrics = self._step_plan(batch)
             else:
@@ -931,30 +944,31 @@ class Trainer:
                     self.params, self.opt_state, batch,
                     jnp.asarray(self.step)
                 )
-            if indices is not None:
-                self._recent_indices.append(
-                    (self.step, np.asarray(indices)))
+        if indices is not None:
+            self._recent_indices.append(
+                (self.step, np.asarray(indices)))
+        with spans.span("train.loss_read"):
             loss = float(metrics["loss"])
-            # a scaler-skipped overflow step (grads_finite == 0) is NOT
-            # poison: the update was rejected and the scale backed off,
-            # so params are untouched (DESIGN.md §4)
-            skipped = not bool(metrics.get("grads_finite", 1.0))
-            if self.sentinel is not None:
-                if self.sentinel.record(loss, scaler_skipped=skipped):
-                    self._rollback()
-                    continue
-            elif not jnp.isfinite(loss) and not skipped:
-                # legacy NaN guard: roll back rather than poison the run
-                if self.maybe_restore():
-                    continue
-                raise FloatingPointError(f"non-finite loss at step {self.step}")
-            self.step += 1
-            self.straggler.record(time.perf_counter() - t0)
-            self._maybe_refit_cost_model()
-            history.append({k: float(v) for k, v in metrics.items()})
-            if self.ckpt_dir is not None and self.step % self.ckpt_every == 0:
-                # only checkpoint states the sentinel considers healthy,
-                # so every file on disk is a known-good rollback target
-                if self.sentinel is None or not self.sentinel.suspicious:
+        # a scaler-skipped overflow step (grads_finite == 0) is NOT
+        # poison: the update was rejected and the scale backed off,
+        # so params are untouched (DESIGN.md §4)
+        skipped = not bool(metrics.get("grads_finite", 1.0))
+        if self.sentinel is not None:
+            if self.sentinel.record(loss, scaler_skipped=skipped):
+                self._rollback()
+                return
+        elif not jnp.isfinite(loss) and not skipped:
+            # legacy NaN guard: roll back rather than poison the run
+            if self.maybe_restore():
+                return
+            raise FloatingPointError(f"non-finite loss at step {self.step}")
+        self.step += 1
+        self.straggler.record(time.perf_counter() - t0)
+        self._maybe_refit_cost_model()
+        history.append({k: float(v) for k, v in metrics.items()})
+        if self.ckpt_dir is not None and self.step % self.ckpt_every == 0:
+            # only checkpoint states the sentinel considers healthy,
+            # so every file on disk is a known-good rollback target
+            if self.sentinel is None or not self.sentinel.suspicious:
+                with spans.span("train.ckpt"):
                     self.save()
-        return history
