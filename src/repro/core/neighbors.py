@@ -47,9 +47,9 @@ class GraphIndices:
     """Pure index representation of G^a and G^b for one crystal.
 
     Layout invariant (DESIGN.md §1): ``bond_center`` is non-decreasing and
-    ``angle_ij`` is non-decreasing — ``_graph_from_pairs`` canonicalizes
-    every producer (``build_graph`` and the Verlet ``update`` refilter), so
-    batch packing only has to merge already-sorted runs.
+    ``angle_ij`` is non-decreasing — ``build_graph`` sorts its pairs by
+    center and the Verlet ``update`` refilter keeps its center-sorted
+    candidates in order, so batch packing only has to merge sorted runs.
 
     Mirror maps (DESIGN.md §5): every directed bond (i, j, n) has a mirror
     (j, i, -n); ``bond_pair`` maps each directed bond to its *undirected*
@@ -59,8 +59,9 @@ class GraphIndices:
     (center, nbr, image) triple IS the stored orientation.  Graphs whose
     pair symmetry was broken (``max_nbr_per_atom`` capping) fall back to
     singleton undirected entries, so the maps are total either way.
-    ``_graph_from_pairs`` always populates them; hand-built instances may
-    leave them ``None`` and let packing repair via ``build_mirror_maps``.
+    ``build_graph`` and ``VerletNeighborList.update`` always populate
+    them; hand-built instances may leave them ``None`` and let packing
+    repair via ``build_mirror_maps``.
     """
 
     bond_center: np.ndarray  # (Nb,) int32 atom index i
@@ -143,36 +144,46 @@ def _candidate_pairs(
 
 
 def _build_angles(
-    bond_center: np.ndarray, bond_dist: np.ndarray, r_cut_bond: float, n: int
-) -> tuple[np.ndarray, np.ndarray]:
-    """Ordered pairs of *short* bonds sharing a center (G^b edges)."""
+    bond_center: np.ndarray, bond_dist: np.ndarray, r_cut_bond: float
+) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+    """Ordered pairs of *short* bonds sharing a center (G^b edges), and
+    their angle-pair dedup maps.
+
+    Short bonds are grouped by center (ascending centers; within a center,
+    ascending bond index).  A group of ``d`` bonds emits every ordered
+    pair (p, q), p != q, of its members in row-major order: ``d - 1`` rows
+    led by each member.  Bond indices ascend within a group, so
+    ``ij < ik`` iff ``p < q``, and the mirror of row (p, q) is row (q, p)
+    of the same group: the maps are those of ``build_angle_mirror_maps``
+    (its reference) without its sort.
+
+    Returns ``(angle_ij, angle_ik, angle_pair, und_angle_rep)``, int32.
+    """
     short = np.nonzero(bond_dist <= r_cut_bond)[0]  # indices into bonds
-    angle_ij_list: list[np.ndarray] = []
-    angle_ik_list: list[np.ndarray] = []
-    if short.size > 0:
-        centers_short = bond_center[short]
-        order = np.argsort(centers_short, kind="stable")
-        short_sorted = short[order]
-        centers_sorted = centers_short[order]
-        # group boundaries
-        starts = np.searchsorted(centers_sorted, np.arange(n), side="left")
-        ends = np.searchsorted(centers_sorted, np.arange(n), side="right")
-        for a in range(n):
-            grp = short_sorted[starts[a]:ends[a]]
-            d = grp.shape[0]
-            if d < 2:
-                continue
-            jj, kk = np.meshgrid(grp, grp, indexing="ij")
-            off = ~np.eye(d, dtype=bool)
-            angle_ij_list.append(jj[off].ravel())
-            angle_ik_list.append(kk[off].ravel())
-    if angle_ij_list:
-        angle_ij = np.concatenate(angle_ij_list).astype(np.int32)
-        angle_ik = np.concatenate(angle_ik_list).astype(np.int32)
-    else:
-        angle_ij = np.zeros((0,), dtype=np.int32)
-        angle_ik = np.zeros((0,), dtype=np.int32)
-    return angle_ij, angle_ik
+    centers = bond_center[short]
+    order = np.argsort(centers, kind="stable")
+    grp = short[order]  # short bonds, grouped by center
+    size = np.bincount(centers)  # group size per center
+    start = (np.cumsum(size) - size)[centers[order]]  # member's group start
+    d = size[centers[order]]
+    lead = np.arange(grp.size) - start  # member's position p in its group
+    rows = d - 1  # rows each member leads as ij
+    first_row = np.cumsum(rows) - rows
+    owner = np.repeat(np.arange(grp.size), rows)  # ij member of each row
+    p = lead[owner]
+    q = np.arange(owner.size) - first_row[owner]  # q, skipping p itself
+    q += q >= p
+    partner = start[owner] + q  # ik member of each row
+    angle_ij = grp[owner].astype(np.int32)
+    angle_ik = grp[partner].astype(np.int32)
+    # mirror row (q, p): led by member ``partner``, p's column skips q
+    mirror = first_row[partner] + p - (p > q)
+    canon = p < q
+    rank = np.cumsum(canon) - 1
+    angle_pair = rank[np.where(canon, np.arange(owner.size), mirror)]
+    und_angle_rep = np.nonzero(canon)[0]
+    return (angle_ij, angle_ik, angle_pair.astype(np.int32),
+            und_angle_rep.astype(np.int32))
 
 
 def _lex_less(a: np.ndarray, b: np.ndarray) -> np.ndarray:
@@ -325,20 +336,23 @@ def build_angle_mirror_maps(
     return angle_pair, und_angle_rep
 
 
-def _mirror_partner(ci: np.ndarray, nj: np.ndarray,
-                    images: np.ndarray) -> np.ndarray:
-    """Index of each directed pair's mirror (j, i, -n) in the same list.
+def _mirror_partner(
+    ci: np.ndarray, nj: np.ndarray, images: np.ndarray
+) -> tuple[np.ndarray, np.ndarray]:
+    """Index of each directed pair's mirror (j, i, -n) in the same list,
+    and whether the pair is the canonical orientation of the two.
 
     Pairs whose mirror is absent (asymmetric input) map to themselves.
-    Uses the same canonical-key grouping as ``build_mirror_maps``.
+    Uses the same canonical key and grouping as ``build_mirror_maps``.
     """
     e_cnt = int(ci.shape[0])
     if e_cnt == 0:
-        return np.zeros((0,), np.int64)
+        return np.zeros((0,), np.int64), np.zeros((0,), bool)
     img = images.astype(np.int64)
     fwd = np.column_stack([ci.astype(np.int64), nj.astype(np.int64), img])
     rev = np.column_stack([nj.astype(np.int64), ci.astype(np.int64), -img])
-    key = np.where(_lex_less(fwd, rev)[:, None], fwd, rev)
+    is_canon = _lex_less(fwd, rev)
+    key = np.where(is_canon[:, None], fwd, rev)
     order = np.lexsort(key.T[::-1])
     ks = key[order]
     boundary = np.empty(e_cnt, dtype=bool)
@@ -352,7 +366,7 @@ def _mirror_partner(ci: np.ndarray, nj: np.ndarray,
     np.add.at(sums, gid, np.arange(e_cnt))
     np.add.at(counts, gid, 1)
     idx = np.arange(e_cnt)
-    return np.where(counts[gid] == 2, sums[gid] - idx, idx)
+    return np.where(counts[gid] == 2, sums[gid] - idx, idx), is_canon
 
 
 def _graph_from_pairs(
@@ -386,15 +400,14 @@ def _graph_from_pairs(
             # undirected half-graph store (§5) never needs a singleton
             # fallback.  Per-atom degree can undershoot the cap (a kept
             # slot whose mirror lost out is dropped), never overshoot.
-            partner = _mirror_partner(ci, nj, images)
+            partner, _ = _mirror_partner(ci, nj, images)
             keep = keep & keep[partner]
         ci, nj, images, dist = ci[keep], nj[keep], images[keep], dist[keep]
 
     # Sorted-segment invariant: bonds sorted by center (stable — preserves
     # the by-distance neighbor order within a center when capped above).
     # ``_candidate_pairs`` already emits centers in row-major order, so
-    # this is a near-identity pass; the Verlet refilter path inherits the
-    # guarantee for free since boolean keep-masks preserve order.
+    # this is a near-identity pass.
     if ci.size and np.any(np.diff(ci) < 0):
         order = np.argsort(ci, kind="stable")
         ci, nj, images, dist = ci[order], nj[order], images[order], dist[order]
@@ -402,24 +415,27 @@ def _graph_from_pairs(
     bond_center = ci.astype(np.int32)
     bond_nbr = nj.astype(np.int32)
     bond_image = images.astype(np.int32)
+    # mirror maps (DESIGN.md §5), canonicalized from the filtered pairs
+    return _assemble(bond_center, bond_nbr, bond_image, dist, r_cut_bond,
+                     build_mirror_maps(bond_center, bond_nbr, bond_image))
 
-    angle_ij, angle_ik = _build_angles(bond_center, dist, r_cut_bond, n)
-    # _build_angles walks centers (and within them, sorted short-bond
-    # groups) in ascending order, so angle_ij is non-decreasing already;
-    # assert cheaply rather than re-sorting.
+
+def _assemble(
+    bond_center: np.ndarray,
+    bond_nbr: np.ndarray,
+    bond_image: np.ndarray,
+    dist: np.ndarray,
+    r_cut_bond: float,
+    bond_maps: tuple[np.ndarray, np.ndarray, np.ndarray],
+) -> GraphIndices:
+    """GraphIndices of center-sorted int32 bonds and their mirror maps."""
+    # angles and their dedup maps: the ordered angle list holds each
+    # unordered {ij, ik} twice, so the model runs angle geometry / Fourier
+    # / embed at Au == Na/2 rows; centers ascend, so angle_ij does too
+    angle_ij, angle_ik, angle_pair, und_angle_rep = _build_angles(
+        bond_center, dist, r_cut_bond)
     assert angle_ij.size == 0 or np.all(np.diff(angle_ij) >= 0)
-
-    # mirror maps (DESIGN.md §5): recomputed from the filtered pairs, so
-    # every producer — build_graph AND the Verlet refilter, whose boolean
-    # keep-masks preserve pair symmetry exactly (|-v| == |v| bitwise) —
-    # emits canonicalized maps
-    bond_pair, bond_sign, und_rep = build_mirror_maps(
-        bond_center, bond_nbr, bond_image)
-    # angle-pair dedup maps: the ordered angle list holds each unordered
-    # {ij, ik} twice — build the (angle_pair, und_angle_rep) maps so the
-    # model can run angle geometry/Fourier/embed at Au == Na/2 rows
-    angle_pair, und_angle_rep = build_angle_mirror_maps(angle_ij, angle_ik)
-
+    bond_pair, bond_sign, und_rep = bond_maps
     return GraphIndices(
         bond_center=bond_center,
         bond_nbr=bond_nbr,
@@ -473,6 +489,12 @@ class VerletNeighborList:
     rebuild — the classical Verlet-list guarantee that no pair can enter
     the cutoff unseen.  The per-step refilter keeps the result *exactly*
     equal to a from-scratch ``build_graph`` at the current positions.
+
+    The rebuild also fixes each candidate's mirror partner and canonical
+    orientation, so a step's mirror maps follow from its keep mask by
+    index arithmetic (``_refilter_maps``) instead of a sort.
+    ``singleton_bonds`` counts the kept bonds of the last update whose
+    mirror was filtered out (the maps' singleton fallback).
     """
 
     def __init__(
@@ -489,6 +511,7 @@ class VerletNeighborList:
         self.skin = skin
         self.rebuilds = 0
         self.updates = 0
+        self.singleton_bonds = 0
         self._rebuild(crystal)
 
     def _rebuild(self, crystal: Crystal) -> None:
@@ -498,9 +521,12 @@ class VerletNeighborList:
             ci, nj, images, _ = _candidate_pairs(
                 lat, frac, self.r_cut_atom + self.skin
             )
-            self._ci, self._nj, self._images = ci, nj, images
+            self._partner, self._canon = _mirror_partner(ci, nj, images)
+            self._ci, self._nj = ci.astype(np.int32), nj.astype(np.int32)
+            self._images = images.astype(np.int32)
             self._ref_lat = lat.copy()
             self._ref_frac = frac.copy()
+            self._ref_shifts = images @ lat
         self.rebuilds += 1
 
     def max_displacement(self, crystal: Crystal) -> float:
@@ -529,15 +555,42 @@ class VerletNeighborList:
         # they stay consistent with the wrapped coordinates the model sees.
         wrap = np.round(frac - self._ref_frac)
         cart = (frac - wrap) @ lat  # continuous (unwrapped) positions
-        vec = (cart[self._nj] + self._images @ lat - cart[self._ci])
+        shifts = (self._ref_shifts if np.array_equal(lat, self._ref_lat)
+                  else self._images @ lat)
+        vec = np.take(cart, self._nj, axis=0)
+        vec += shifts
+        vec -= np.take(cart, self._ci, axis=0)
         dist = np.linalg.norm(vec, axis=-1)
         keep = (dist <= self.r_cut_atom) & (dist > 1e-8)
-        images = (
-            self._images[keep]
-            - wrap[self._nj[keep]].astype(np.int64)
-            + wrap[self._ci[keep]].astype(np.int64)
-        )
-        return _graph_from_pairs(
-            self._ci[keep], self._nj[keep], images, dist[keep],
-            n=crystal.num_atoms, r_cut_bond=self.r_cut_bond,
-        )
+        kept = np.flatnonzero(keep)
+        ci, nj = self._ci[kept], self._nj[kept]
+        wrap = wrap.astype(np.int32)
+        images = np.take(self._images, kept, axis=0)
+        images -= np.take(wrap, nj, axis=0)
+        images += np.take(wrap, ci, axis=0)
+        # candidates are center-sorted and a keep mask preserves order, so
+        # the kept pairs already satisfy the sorted-segment invariant
+        return _assemble(ci, nj, images, dist[kept], self.r_cut_bond,
+                         self._refilter_maps(keep, kept))
+
+    def _refilter_maps(
+        self, keep: np.ndarray, kept: np.ndarray
+    ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """``build_mirror_maps`` of the kept candidates, in O(E), no sort.
+
+        The wrap shift of ``update`` maps a candidate's mirror to the kept
+        pair's mirror and leaves its canonical orientation as it was, so
+        a kept bond is its undirected entry's representative iff it is
+        canonical or its mirror was filtered out (the singleton fallback);
+        entries are numbered by ascending representative position.
+        """
+        partner = self._partner[kept]
+        paired = (partner != kept) & keep[partner]
+        is_rep = self._canon[kept] | ~paired
+        self.singleton_bonds = int(kept.size - np.count_nonzero(paired))
+        position = np.cumsum(keep) - 1  # candidate -> kept position
+        rank = np.cumsum(is_rep) - 1
+        rep = np.where(is_rep, np.arange(kept.size), position[partner])
+        return (rank[rep].astype(np.int32),
+                np.where(is_rep, 1.0, -1.0).astype(np.float32),
+                np.nonzero(is_rep)[0].astype(np.int32))

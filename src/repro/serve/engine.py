@@ -339,5 +339,7 @@ class BatchedMD:
             steps_done=self.steps_done,
             nlist_rebuilds=sum(r.nlist.rebuilds for r in self.replicas),
             nlist_updates=sum(r.nlist.updates for r in self.replicas),
+            nlist_singleton_bonds=sum(
+                r.nlist.singleton_bonds for r in self.replicas),
         )
         return s
