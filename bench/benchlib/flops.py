@@ -13,6 +13,11 @@ def gemm_flops(rows: int, d_in: int, d_out: int) -> float:
     return 2.0 * rows * d_in * d_out
 
 
+def _magmom_head_flops(cfg: dict, atoms: int) -> float:
+    d = cfg["dim"]
+    return gemm_flops(atoms, d, d) + gemm_flops(atoms, d, 1)
+
+
 def forward_flops(cfg: dict, atoms: int, bonds: int, angles: int) -> float:
     """One forward pass of ``cfg`` (a ``configs/*.json`` dict) over real
     rows, up to and including the readout heads the configuration has."""
@@ -26,7 +31,7 @@ def forward_flops(cfg: dict, atoms: int, bonds: int, angles: int) -> float:
              + gemm_flops(angles, 4 * d, 2 * d))   # angle update GatedMLP
     final = gemm_flops(bonds, 3 * d, 2 * d) + gemm_flops(atoms, d, d)
     heads = (gemm_flops(atoms, d, d) * 2 + gemm_flops(atoms, d, 1)  # energy
-             + gemm_flops(atoms, d, d) + gemm_flops(atoms, d, 1))   # magmom
+             + _magmom_head_flops(cfg, atoms))
     if cfg["readout"] == "direct":
         heads += (gemm_flops(bonds, d, d) + gemm_flops(bonds, d, 1)  # force
                   + gemm_flops(atoms, d, d) + gemm_flops(atoms, d, 9))
@@ -34,14 +39,26 @@ def forward_flops(cfg: dict, atoms: int, bonds: int, angles: int) -> float:
 
 
 def train_step_flops(cfg: dict, atoms: int, bonds: int, angles: int) -> float:
-    """Forward and backward of a training step: the backward of every
-    GEMM takes two GEMMs of its size (input and weight gradients).  Under
-    the autodiff readout the forward itself holds a backward to the
-    positions, and training differentiates that again; only the direct
-    readout is counted here."""
-    if cfg["readout"] != "direct":
-        raise ValueError("train FLOPs are counted for the direct readout")
-    return 3.0 * forward_flops(cfg, atoms, bonds, angles)
+    """Forward and backward of a training step.
+
+    A GEMM of cost F costs F forward, F for an input-only gradient and 2F
+    for a full backward (input and weight gradients).  Under the direct
+    readout every GEMM runs forward and full backward: 3F.
+
+    Under the autodiff readout the forces and stress are -dE/dx and dE/de,
+    so the loss holds the inner backward of the energy to displacement and
+    strain (input gradients only, one GEMM of the transposed weight per
+    GEMM, as ``serve_step_flops`` counts it), and training differentiates
+    that again.  Each GEMM on the energy path then costs F forward, F in
+    the inner backward, and 4F in the outer backward, which takes the
+    full backward of both the forward GEMM and its transpose in the inner
+    backward: 6F.  The magmom head is not on the energy path: 3F.
+    """
+    f = forward_flops(cfg, atoms, bonds, angles)
+    if cfg["readout"] == "direct":
+        return 3.0 * f
+    magmom = _magmom_head_flops(cfg, atoms)
+    return 6.0 * (f - magmom) + 3.0 * magmom
 
 
 def serve_step_flops(cfg: dict, atoms: int, bonds: int, angles: int) -> float:

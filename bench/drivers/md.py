@@ -26,7 +26,8 @@ import time
 
 import numpy as np
 
-from benchlib import cells, compare, crystals, flops, reference, trace, weights
+from benchlib import (cells, compare, crystals, flops, reference, spans,
+                      trace, weights)
 
 
 class Recorder:
@@ -111,6 +112,7 @@ def run(ctx) -> dict:
         rec.step()
     first = len(rec.forces)
     rec.fill = [0.0, 0]
+    before = md.stats()
 
     logdir = tempfile.mkdtemp(prefix="bench_trace_") if ctx.trace else None
     if ctx.trace:
@@ -139,12 +141,18 @@ def run(ctx) -> dict:
     # per-step rows for the FLOP count: the graphs of the stepped
     # positions, counted by the benchmark's own neighbor search
     readings = {"window_s": t1 - t0, "steps": steps,
-                "fill.md": tuple(rec.fill)}
+                "fill.md": tuple(rec.fill),
+                "nlist.md": {k: stats[k] - before[k]
+                             for k in ("nlist_rebuilds", "nlist_updates")}}
     reduced = None
     if ctx.trace:
         try:
             reduced = trace.reduce(trace.load(trace.find_xplane(logdir)),
                                    steps=steps)
+            # op names live in the trace-viewer file, which an MD window
+            # passes the size cap of: host spans and busy time only
+            readings["spans"] = spans.reduce(spans.load(
+                trace.find_xplane(logdir), op_names=False))
         finally:
             shutil.rmtree(logdir, ignore_errors=True)
     del md, serve, params

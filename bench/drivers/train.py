@@ -26,7 +26,8 @@ import time
 
 import numpy as np
 
-from benchlib import cells, compare, crystals, flops, reference, trace, weights
+from benchlib import (cells, compare, crystals, flops, reference, spans,
+                      trace, weights)
 
 REFERENCE_STEPS = 3
 
@@ -209,6 +210,8 @@ def run(ctx) -> dict:
         try:
             reduced = trace.reduce(trace.load(trace.find_xplane(logdir)),
                                    steps=steps)
+            readings["spans"] = spans.reduce(spans.load(
+                trace.find_xplane(logdir), op_names=True))
         finally:
             shutil.rmtree(logdir, ignore_errors=True)
     ref_indices = feed.indices[:REFERENCE_STEPS]

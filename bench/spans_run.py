@@ -11,7 +11,9 @@ and the program's counters are read against their values as the session
 opened.  The last line of standard output is one JSON object: ``correct``,
 the end-to-end numbers, ``numbers`` (the per-step span times, device
 shares and rebuild share below), ``counters`` and the whole reduction.
-The benchmark's own runs (``bench/run.py``) do not read these.
+The benchmark's own ``--trace 1`` runs read the same numbers through
+the readers in ``bench/metrics``, which call the functions ``NUMBERS``
+calls.
 """
 from __future__ import annotations
 

@@ -27,13 +27,20 @@ MD_TRAFFIC = {"driver": "md", "replicas": 4, "smallest": 8, "largest": 20,
               "warm_steps": 2, "checked_steps": 2, "reference_block": 2}
 
 
-def small_cell(monkeypatch, workload: str, traffic: dict) -> cells.Cell:
+def small_cell(monkeypatch, workload: str, traffic: dict,
+               config: str | None = None) -> cells.Cell:
     """``workload``'s configuration and limits at the small widths, on
-    ``traffic``: the program's preset is narrowed alike."""
+    ``traffic``: the program's preset is narrowed alike.  ``config`` names
+    another ``BENCHMARK.json`` configuration to run under those limits."""
     from repro.configs import chgnet_mptrj as C
 
     cell = cells.load_cell(workload)
-    cfg = dict(copy.deepcopy(cell.config), **SMALL)
+    base = cell.config
+    if config is not None:
+        entry = next(c for c in cells.benchmark()["configs"]
+                     if c["name"] == config)
+        base = cells._json(ROOT, entry["file"])
+    cfg = dict(copy.deepcopy(base), **SMALL)
     preset = f"_SMALL_{cfg['preset']}"
     monkeypatch.setattr(C, preset,
                         getattr(C, cfg["preset"]).with_(**SMALL),

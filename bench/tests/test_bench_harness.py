@@ -10,7 +10,7 @@ import sys
 import numpy as np
 import pytest
 
-from harness_util import BENCH_DIR, ROOT, benchmark
+from harness_util import BENCH_DIR, ROOT, SMALL, benchmark
 from benchlib import cells, crystals, flops, peaks
 
 BENCH = benchmark()
@@ -124,6 +124,33 @@ def test_flops_match_the_gemm_arithmetic(launcher_batches):
     ad = dict(cfg, readout="autodiff")
     assert flops.serve_step_flops(ad, atoms, bonds, angles) == \
         2 * flops.forward_flops(ad, atoms, bonds, angles)
+
+
+def _hand_count(readout: str, atoms: int, bonds: int, angles: int) -> float:
+    """A training step's GEMM FLOPs at the ``SMALL`` widths (dim 8, 5 + 5
+    bases, one block), written out GEMM by GEMM: 2 rows d_in d_out each."""
+    energy_path = (2 * bonds * 5 * 24 + 2 * angles * 5 * 8          # embeds
+                   + 2 * bonds * 24 * 16 + 2 * atoms * 8 * 8        # block
+                   + 2 * angles * 32 * 16 + 2 * bonds * 8 * 8
+                   + 2 * angles * 32 * 16
+                   + 2 * bonds * 24 * 16 + 2 * atoms * 8 * 8        # final
+                   + 2 * (2 * atoms * 8 * 8) + 2 * atoms * 8)       # energy
+    magmom = 2 * atoms * 8 * 8 + 2 * atoms * 8
+    if readout == "direct":
+        heads = (2 * bonds * 8 * 8 + 2 * bonds * 8                  # force
+                 + 2 * atoms * 8 * 8 + 2 * atoms * 8 * 9)           # stress
+        return 3 * (energy_path + magmom + heads)
+    # autodiff: forward, inner backward to the positions and strain, and
+    # the outer backward of both on the energy path; the magmom head 3x
+    return 6 * energy_path + 3 * magmom
+
+
+@pytest.mark.parametrize("config", ["chgnet_fs", "chgnet_autodiff"])
+def test_train_flops_match_a_hand_count(config):
+    cfg = dict(cells._json(BENCH_DIR, "configs", f"{config}.json"), **SMALL)
+    rows = (37, 412, 1290)
+    assert flops.train_step_flops(cfg, *rows) == _hand_count(
+        cfg["readout"], *rows)
 
 
 class _Null:

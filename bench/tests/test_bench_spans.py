@@ -246,3 +246,54 @@ def test_reduction_on_a_chip_trace_with_spans(path):
     busy = trace.reduce(events, steps=1)["busy_s"]
     assert sum(red["idle"].values()) == pytest.approx(
         red["window_s"] - busy, rel=1e-6)
+
+
+# each span reader of BENCHMARK.json, and the recorded chip trace of a
+# cell it reads
+SPAN_READERS = {
+    "md_nlist_ms_per_step": "spans_md_64rep_fs.json.gz",
+    "md_pack_ms_per_step": "spans_md_64rep_fs.json.gz",
+    "md_collect_ms_per_step": "spans_md_64rep_fs.json.gz",
+    "train_data_wait_ms_per_step": "spans_train_b128_fs.json.gz",
+    "device_share.train.blocks": "spans_train_b128_fs.json.gz",
+    "device_share.train.readout": "spans_train_b128_fs.json.gz",
+    "device_share.train.optimizer": "spans_train_b128_fs.json.gz",
+    "device_share.train.unscoped": "spans_train_b128_fs.json.gz",
+}
+
+
+@pytest.mark.parametrize("name", sorted(SPAN_READERS))
+def test_span_reader_on_a_chip_trace_with_spans(name):
+    """Each reader gives, from the driver's reading of a recorded chip
+    trace, the number ``spans_run.py`` prints under its name."""
+    red = spans.reduce(spans.read(os.path.join(BENCH_DIR, "data",
+                                               SPAN_READERS[name])))
+    got = cells.reader(name)({"spans": red})
+    assert got is not None and got >= 0
+    assert got == spans_run.NUMBERS[name]({"spans": red})
+    if name.startswith("device_share."):
+        assert got <= 100
+
+
+@pytest.mark.parametrize("name", sorted(SPAN_READERS))
+def test_span_reader_reads_nothing_without_program_spans(name):
+    """On a chip trace of the program before it had spans or scopes, and
+    on a run without a trace, no span reader returns a number."""
+    events = trace.read(OLD[0])
+    win = next(h for h in events["host"] if h[2] == trace.WINDOW_SPAN)
+    red = spans.reduce({"window": win[:2], "spans": [],
+                        "devices": events["devices"]})
+    read = cells.reader(name)
+    assert read({"spans": red}) is None
+    assert read({}) is None
+
+
+def test_rebuild_share_reads_the_window_counts():
+    read = cells.reader("nlist_rebuild_share.md")
+    assert read({"nlist.md": {"nlist_rebuilds": 3, "nlist_updates": 12}}) \
+        == pytest.approx(25.0)
+    assert read({"nlist.md": {"nlist_rebuilds": 0, "nlist_updates": 640}}) \
+        == 0.0
+    assert read({"nlist.md": {"nlist_rebuilds": 0, "nlist_updates": 0}}) \
+        is None
+    assert read({}) is None

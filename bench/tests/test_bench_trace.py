@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 from harness_util import BENCH_DIR
-from benchlib import trace
+from benchlib import cells, trace
 
 RECORDED = sorted(glob.glob(os.path.join(BENCH_DIR, "data", "*.json.gz")))
 
@@ -63,9 +63,9 @@ def test_reduction_on_a_chip_trace(path):
     assert got["busy_s"] > 0
 
 
-def test_reduction_on_a_hand_made_trace():
+def _two_devices():
     ms = 1_000_000
-    events = {
+    return {
         "host": [[0, 100 * ms, "bench.window"],
                  [0, 10 * ms, "bench.data_next"],
                  [50 * ms, 30 * ms, "bench.md_step"],
@@ -77,7 +77,10 @@ def test_reduction_on_a_hand_made_trace():
             "/device:TPU:1": [[10 * ms, 40 * ms, "all-reduce.2"]],
         },
     }
-    got = _check(events, steps=2)
+
+
+def test_reduction_on_a_hand_made_trace():
+    got = _check(_two_devices(), steps=2)
     # device 0 busy 10-50 and 90-100 ms; device 1 busy 10-50 ms
     assert got["busy_s"] == pytest.approx((0.050 + 0.040) / 2)
     # exposed all-reduce: device 0 40-50 ms, device 1 10-50 ms, per step
@@ -88,3 +91,18 @@ def test_reduction_on_a_hand_made_trace():
     # idle 0-10 ms and 50-100 ms, whose middle falls in md_step too
     assert gaps == pytest.approx({"data_next": 0.010,
                                   "md_step": (0.040 + 0.050) / 2})
+
+
+def test_exposed_collective_reader_on_a_hand_made_trace():
+    """``dp_exposed_collective_ms``: the all-reduce's exposed time per
+    step, averaged over the two devices, in ms; nothing to read where no
+    collective ran."""
+    read = cells.reader("dp_exposed_collective_ms")
+    events = _two_devices()
+    got = read({"trace": trace.reduce(events, steps=2)})
+    assert got == pytest.approx(1e3 * (0.010 + 0.040) / 2 / 2)
+    for evs in events["devices"].values():
+        for ev in evs:
+            ev[2] = ev[2].replace("all-reduce", "fusion")
+    assert read({"trace": trace.reduce(events, steps=2)}) is None
+    assert read({"trace": None}) is None
